@@ -179,6 +179,8 @@ def _report_command(args, session=None) -> int:
     num_failed = 0
     if not args.results:
         _preset_device_count([(n, s) for _, n, s in entries])
+        from ..launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     for path, name, spec in entries:
         out_dir = os.path.join(args.out, name)
         if args.results:
@@ -455,6 +457,8 @@ def main(argv: list[str] | None = None) -> int:
     specs = load_specs(args.spec, session=session)
     if not args.server:
         _preset_device_count(specs)
+        from ..launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     multi = len(specs) > 1
     failed = 0
     for name, spec in specs:

@@ -312,7 +312,8 @@ def _synthesize_serving(spec: WorkloadSpec) -> Workload:
 
 
 def _mesh_for(spec: WorkloadSpec):
-    """Build the spec's device mesh (None when the spec has none)."""
+    """Build the spec's export mesh (None when the spec has none): a mesh
+    of CPU devices on every host, since exports compile for the CPU."""
     if spec.mesh is None:
         return None
     import jax
@@ -323,10 +324,10 @@ def _mesh_for(spec: WorkloadSpec):
     need = 1
     for s in shape:
         need *= s
-    have = len(jax.devices())
+    have = len(jax.devices("cpu"))
     if need > have:
         raise ValueError(
-            f"workload {spec.name!r}: mesh {shape} needs {need} devices "
+            f"workload {spec.name!r}: mesh {shape} needs {need} CPU devices "
             f"but only {have} are visible — set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} before jax "
             "starts (the repro.campaign CLI does this automatically)")

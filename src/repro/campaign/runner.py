@@ -454,6 +454,8 @@ def run_campaign(spec: CampaignSpec, *,
     t0 = time.perf_counter()
     spec.validate(provided=set(workloads or {}), session=session)
     regs = _Registries.for_session(session, spec)
+    if executor == "process":
+        _refuse_device_holders(spec, regs.estimators or ESTIMATORS)
     jobs = spec.expand()
     resumed: dict[int, dict] = {}
     resume_report: dict | None = None
@@ -659,6 +661,21 @@ def _run_in_process(chains: list[list[JobSpec]], plan_keys: dict,
             _drain_chains(pool, chains,
                           submit=lambda job, lead: pool.submit(run_one, job))
     return rows, len(new_keys), retried[0]
+
+
+def _refuse_device_holders(spec: CampaignSpec, estimators) -> None:
+    """The process executor starts several processes, and a device
+    belongs to one process: an estimator kind that runs on the device
+    (``holds_device``) must run in this process, under threads."""
+    held = sorted({e.kind for e in spec.estimators
+                   if getattr(estimators.get(e.kind), "holds_device",
+                              False)})
+    if held:
+        raise ValueError(
+            f"campaign {spec.name!r}: estimator kind(s) {held} run on this "
+            "process's device, which one process owns; the process "
+            "executor would start several — use executor='thread' "
+            "(--executor thread)")
 
 
 def _drain_chains(pool: Executor, chains: list[list[JobSpec]],

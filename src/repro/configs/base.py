@@ -195,6 +195,7 @@ class RunConfig:
     mesh_shape: tuple[int, ...] = (16, 16)
     mesh_axes: tuple[str, ...] = ("data", "model")
     learning_rate: float = 3e-4
+    warmup_steps: int = 100        # linear learning-rate warmup
     weight_decay: float = 0.1
     optimizer: str = "adamw"       # adamw | adafactor
     grad_clip: float = 1.0

@@ -30,6 +30,14 @@ _DEFAULT_DIR = (os.environ.get("REPRO_SYSTEMS_DIR")
                 or os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "..", "..", "..", "specs", "systems"))
 
+#: ``jax.Device.device_kind`` -> catalog id of the system that device is.
+#: The profiling tier and ``chip_smoke.py`` name what they measure through
+#: this table; a kind missing here is an error, never a default peak.
+DEVICE_KINDS = {
+    "cpu": "host",
+    "TPU v5 lite": "tpu-v5e",
+}
+
 _REQUIRED_FIELDS = ("id", "name", "peak_flops", "mem_bw", "mem_capacity",
                     "interconnect")
 
@@ -212,6 +220,18 @@ class SystemRegistry:
     def scope(self) -> "SystemRegistry":
         """A child registry: local catalogs/registrations, parent fallback."""
         return SystemRegistry(parent=self)
+
+
+def system_id_for_device(device) -> str:
+    """Catalog id of a ``jax.Device`` (``TPU v5 lite`` -> ``tpu-v5e``);
+    raises ``KeyError`` for a device kind the table does not know."""
+    kind = device.device_kind
+    if kind not in DEVICE_KINDS:
+        raise KeyError(
+            f"device kind {kind!r} ({device.platform}) is not in "
+            f"repro.core.catalog.DEVICE_KINDS {sorted(DEVICE_KINDS)}; add "
+            "it with the catalog id of its system record")
+    return DEVICE_KINDS[kind]
 
 
 _DEFAULT: SystemRegistry | None = None

@@ -63,20 +63,34 @@ def export_workload(jitted, *specs, name: str = "workload",
 
     ``jitted`` must be a ``jax.jit`` result; ``specs`` are
     ShapeDtypeStructs (sharded or not) — no device allocation happens.
+
+    Lowering and compilation target the CPU backend on every host: specs
+    without a sharding land on the first CPU device, and sharded specs
+    must carry a mesh of CPU devices (:func:`repro.launch.mesh.make_mesh`).
+    The texts, and every prediction made from them, are then the same
+    bytes on a CPU-only machine and on an accelerator host.
     """
-    lowered = jitted.lower(*specs, **kw)
-    w = Workload(name=name, stablehlo_text=lowered.as_text())
-    if compile_workload:
-        compiled = lowered.compile()
+    import jax
+    from jax._src import config
+
+    for leaf in jax.tree.leaves((specs, kw)):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None and any(
+                d.platform != "cpu" for d in sharding.device_set):
+            raise ValueError(
+                f"workload {name!r}: export specs must be placed on CPU "
+                f"devices, got {sharding}")
+    # no caller frames in the op locations: the texts must not depend on
+    # who called the export
+    with jax.default_device(jax.devices("cpu")[0]), \
+            config.include_full_tracebacks_in_locations(False):
+        lowered = jitted.lower(*specs, **kw)
+        w = Workload(name=name, stablehlo_text=lowered.as_text())
+        compiled = lowered.compile() if compile_workload else None
+    if compiled is not None:
         w.hlo_text = compiled.as_text()
         try:
-            ca = compiled.cost_analysis()
-            # jax <= 0.4.x returns a one-element list of dicts.
-            # 0.4.x compat shim: drop the list handling when the jax
-            # floor moves to >= 0.6
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            w.meta["cost_analysis"] = dict(ca or {})
+            w.meta["cost_analysis"] = dict(compiled.cost_analysis() or {})
         except Exception:
             pass
         try:

@@ -102,15 +102,23 @@ def _referenced_functions(raw_text: str, program: Program,
 
 
 def region_to_module(ops: list[OpNode], program: Program,
-                     name: str = "region") -> tuple[str, list[TensorType]]:
+                     name: str = "region"
+                     ) -> tuple[str, list[TensorType], dict[int, int]]:
     """Build a standalone module for a region.
 
-    Returns (module_text, input_types).  External SSA values become function
-    arguments (types resolved from their global defining op); constants and
-    iotas referenced from outside are inlined so regions stay self-contained;
-    every region-defined value not consumed inside is returned, so XLA cannot
-    dead-code-eliminate interior work — mirroring the paper's per-region
-    compilation scope (and its loss of cross-region optimization).
+    Returns (module_text, input_types, aliases).  External SSA values become
+    function arguments (types resolved from their global defining op);
+    constants and iotas referenced from outside are inlined so regions stay
+    self-contained; every region-defined value not consumed inside is
+    returned, so XLA cannot dead-code-eliminate interior work — mirroring
+    the paper's per-region compilation scope (and its loss of cross-region
+    optimization).
+
+    ``aliases`` maps argument index -> result index: each result shares its
+    buffer with the first unclaimed argument of the same type
+    (``tf.aliasing_output``), as a jitted step with donated arguments does.
+    A region that updates state in place (a train step's parameters and
+    optimizer moments) then needs that state once on the device, not twice.
     """
     if program.dialect != "stablehlo":
         raise RegionEmitError("region emission requires the stablehlo dialect")
@@ -198,8 +206,17 @@ def region_to_module(ops: list[OpNode], program: Program,
     body_lines = [l for op in ops for l in rewrite(op.raw).splitlines()]
     body_lines = _strip_sharding_lines(body_lines)
     inline_block = [rewrite(l) for l in inline_lines]
-    args = ", ".join(f"%rin{i}: {_mlir_type(t)}"
-                     for i, (_, t) in enumerate(inputs))
+    aliases: dict[int, int] = {}
+    for j, (_, t) in enumerate(outputs):
+        i = next((i for i, (_, ti) in enumerate(inputs)
+                  if i not in aliases and ti == t), None)
+        if i is not None:
+            aliases[i] = j
+    args = ", ".join(
+        f"%rin{i}: {_mlir_type(t)}"
+        + (f" {{tf.aliasing_output = {aliases[i]} : i32}}"
+           if i in aliases else "")
+        for i, (_, t) in enumerate(inputs))
     ret_names = ", ".join(r for r, _ in outputs)
     ret_types = ", ".join(_mlir_type(t) for _, t in outputs)
 
@@ -220,4 +237,4 @@ def region_to_module(ops: list[OpNode], program: Program,
         + f"\n    return {ret_names} : {ret_types}\n"
         + "  }\n}"
     )
-    return module, [t for _, t in inputs]
+    return module, [t for _, t in inputs], aliases

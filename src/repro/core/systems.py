@@ -112,8 +112,9 @@ def _measure_host_matmul_flops() -> float:
     import jax
     import jax.numpy as jnp
     n = 512
-    a = jnp.ones((n, n), jnp.bfloat16)
-    b = jnp.ones((n, n), jnp.bfloat16)
+    cpu = jax.devices("cpu")[0]   # the host, even where an accelerator is
+    a = jnp.ones((n, n), jnp.bfloat16, device=cpu)
+    b = jnp.ones((n, n), jnp.bfloat16, device=cpu)
     f = jax.jit(lambda x, y: x @ y)
     f(a, b).block_until_ready()  # compile + warmup
     best = float("inf")

@@ -86,7 +86,10 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array, *,
     skv = k.shape[1]
     block_q = min(block_q, sq)
     block_k = min(block_k, skv)
-    assert sq % block_q == 0 and skv % block_k == 0, (sq, skv)
+    if sq % block_q or skv % block_k:
+        raise ValueError(
+            f"flash attention: sequence lengths ({sq}, {skv}) must be "
+            f"multiples of the blocks ({block_q}, {block_k})")
     q_steps = sq // block_q
     kv_steps = skv // block_k
     grid = (bh, q_steps, kv_steps)

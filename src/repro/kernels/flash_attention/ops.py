@@ -10,7 +10,9 @@ from .kernel import flash_attention_kernel
 
 
 def _should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernel on the CPU only; any other backend compiles it
+    (and raises what its compiler refuses)."""
+    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "logit_cap",
@@ -29,7 +31,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     out = flash_attention_kernel(
         q.reshape(b * hq, sq, d), k.reshape(b * hq, skv, d),
         v.reshape(b * hq, skv, d), causal=causal,
-        window=int(window) if isinstance(window, int) else 0,
+        window=window,
         logit_cap=logit_cap, q_offset=q_offset,
         block_q=block_q, block_k=block_k,
         interpret=_should_interpret())

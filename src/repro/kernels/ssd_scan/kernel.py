@@ -5,14 +5,17 @@ attention-like product  Y = (L ∘ (C Bᵀ)) · (dt·X)  plus a contribution fro
 the inbound state; both are dense matmuls — MXU work — while only the
 O(S/L) inter-chunk state recurrence is sequential (left in jnp/lax.scan).
 
-Grid: (batch, heads, chunks).  VMEM blocks per step:
-  x (L×P), dt-weighted x (L×P), B/C (L×N), inbound state (P×N) →
-  outputs y (L×P) and outbound chunk state (P×N).
-L=256, P=64, N=128 → ~400 KB resident; MXU shapes 256×128×64 — aligned.
+Grid: (batch, heads, chunks), all independent.  Operands are head-major
+(``[B, H, C, L, ...]``), so every block's two minor dims are whole array
+dims — (L, P), (L, N), (P, N), (L, 1), (1, L) — which the TPU tiling
+accepts at any width.  VMEM blocks per step: dt-weighted x (L×P), B/C
+(L×N), the chunk's cumulative decays as a column and as a row, inbound
+state (P×N) → output y (L×P).  L=256, P=64, N=128: ~0.5 MB resident
+with the L×L score tile; MXU shapes 256×128×64.
 
 The host wrapper (ops.py) precomputes the cumulative decays (cheap
-elementwise) and runs the inter-chunk scan; the kernel fuses the four
-matmul-heavy contractions of the dual form.
+elementwise) and runs the inter-chunk scan; the kernel fuses the three
+matmul-heavy contractions of the output.
 """
 from __future__ import annotations
 
@@ -21,23 +24,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(x_ref, dtx_ref, b_ref, c_ref, dacs_ref, datot_ref, state_ref,
-            y_ref, os_ref):
-    dacs = dacs_ref[0, 0, :, 0].astype(jnp.float32)        # [L]
-    datot = datot_ref[0, 0, 0].astype(jnp.float32)         # scalar
-    b = b_ref[0, 0, :, 0, :].astype(jnp.float32)           # [L,N]
-    c = c_ref[0, 0, :, 0, :].astype(jnp.float32)           # [L,N]
-    dtx = dtx_ref[0, 0, :, 0, :].astype(jnp.float32)       # [L,P]
-    state = state_ref[0, 0, 0].astype(jnp.float32)         # [P,N]
+def _kernel(dtx_ref, b_ref, c_ref, dacs_col_ref, dacs_row_ref, state_ref,
+            y_ref):
+    dtx = dtx_ref[...].astype(jnp.float32)                 # [L,P]
+    b = b_ref[...].astype(jnp.float32)                     # [L,N]
+    c = c_ref[...].astype(jnp.float32)                     # [L,N]
+    dacs_col = dacs_col_ref[...]                           # [L,1]
+    dacs_row = dacs_row_ref[...]                           # [1,L]
+    state = state_ref[...]                                 # [P,N]
 
     # intra-chunk: scores = (C Bᵀ) ∘ L  where L[i,j] = exp(dacs_i - dacs_j)
     scores = jax.lax.dot_general(
         c, b, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                # [L,L]
-    l = dacs.shape[0]
+    l = scores.shape[0]
     ii = jax.lax.broadcasted_iota(jnp.int32, (l, l), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (l, l), 1)
-    decay = jnp.exp(dacs[:, None] - dacs[None, :])
+    decay = jnp.exp(dacs_col - dacs_row)
     scores = jnp.where(ii >= jj, scores * decay, 0.0)
     y = jax.lax.dot_general(
         scores, dtx, (((1,), (0,)), ((), ())),
@@ -47,47 +50,29 @@ def _kernel(x_ref, dtx_ref, b_ref, c_ref, dacs_ref, datot_ref, state_ref,
     y_off = jax.lax.dot_general(
         c, state, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                # [L,P]
-    y = y + y_off * jnp.exp(dacs)[:, None]
-    y_ref[0, 0, :, 0, :] = y.astype(y_ref.dtype)
-
-    # outbound chunk state: S_c = Σ_t exp(datot - dacs_t) · dtx_t ⊗ B_t
-    w = jnp.exp(datot - dacs)[:, None]                     # [L,1]
-    os_ref[0, 0, 0] = jax.lax.dot_general(
-        dtx * w, b, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # [P,N]
+    y_ref[...] = (y + y_off * jnp.exp(dacs_col)).astype(y_ref.dtype)
 
 
-def ssd_chunk_pallas(x, dtx, b_in, c_in, dacs, datot, states, *,
+def ssd_chunk_pallas(dtx, b_in, c_in, dacs, states, *,
                      interpret: bool = False):
-    """Batched over a (B, H, C) grid.
+    """Chunk outputs over a (B, H, C) grid, head-major operands.
 
-    x, dtx: [B,C,L,H,P]; b_in, c_in: [B,C,L,H,N]; dacs: [B,C,L,H];
-    datot: [B,C,H]; states: [B,C,H,P,N] (inbound state per chunk).
-    Returns (y [B,C,L,H,P] f32, chunk local contributions as in kernel)."""
-    bsz, nc, l, h, p = x.shape
+    dtx: [B,H,C,L,P]; b_in, c_in: [B,H,C,L,N]; dacs: [B,H,C,L] f32
+    (within-chunk cumulative decay); states: [B,H,C,P,N] f32 (inbound
+    state per chunk).  Returns y [B,H,C,L,P] in ``dtx.dtype``."""
+    bsz, h, nc, l, p = dtx.shape
     n = b_in.shape[-1]
 
-    grid = (bsz, h, nc)
-    spec_lp = pl.BlockSpec((1, 1, l, 1, p),
-                           lambda bb, hh, cc: (bb, cc, 0, hh, 0))
-    spec_ln = pl.BlockSpec((1, 1, l, 1, n),
-                           lambda bb, hh, cc: (bb, cc, 0, hh, 0))
-    spec_l = pl.BlockSpec((1, 1, l, 1),
-                          lambda bb, hh, cc: (bb, cc, 0, hh))
-    spec_1 = pl.BlockSpec((1, 1, 1), lambda bb, hh, cc: (bb, cc, hh))
-    spec_pn = pl.BlockSpec((1, 1, 1, p, n),
-                           lambda bb, hh, cc: (bb, cc, hh, 0, 0))
+    def block(*minor):
+        return pl.BlockSpec((None, None, None, *minor),
+                            lambda bb, hh, cc: (bb, hh, cc, 0, 0))
 
-    y, out_states = pl.pallas_call(
+    return pl.pallas_call(
         _kernel,
-        grid=grid,
-        in_specs=[spec_lp, spec_lp, spec_ln, spec_ln, spec_l, spec_1,
-                  spec_pn],
-        out_specs=[spec_lp, spec_pn],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nc, l, h, p), x.dtype),
-            jax.ShapeDtypeStruct((bsz, nc, h, p, n), jnp.float32),
-        ],
+        grid=(bsz, h, nc),
+        in_specs=[block(l, p), block(l, n), block(l, n), block(l, 1),
+                  block(1, l), block(p, n)],
+        out_specs=block(l, p),
+        out_shape=jax.ShapeDtypeStruct((bsz, h, nc, l, p), dtx.dtype),
         interpret=interpret,
-    )(x, dtx, b_in, c_in, dacs, datot, states)
-    return y, out_states
+    )(dtx, b_in, c_in, dacs[..., None], dacs[..., None, :], states)

@@ -11,7 +11,9 @@ from .kernel import ssd_chunk_pallas
 
 
 def _should_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernel on the CPU only; any other backend compiles it
+    (and raises what its compiler refuses)."""
+    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("chunk",))
@@ -62,6 +64,11 @@ def ssd_scan(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
         (local_states.transpose(1, 0, 2, 3, 4), datot.transpose(1, 0, 2)))
     inbound = inbound.transpose(1, 0, 2, 3, 4)             # [B,C,H,P,N]
 
-    y, _ = ssd_chunk_pallas(xc, dtx, bh, ch, dacs, datot, inbound,
-                            interpret=_should_interpret())
+    def head_major(t):                                     # [B,C,L,H,..]
+        return jnp.moveaxis(t, 3, 1)                       # -> [B,H,C,L,..]
+
+    y = ssd_chunk_pallas(head_major(dtx), head_major(bh), head_major(ch),
+                         head_major(dacs), inbound.transpose(0, 2, 1, 3, 4),
+                         interpret=_should_interpret())
+    y = jnp.moveaxis(y, 1, 3)                              # [B,C,L,H,P]
     return y.reshape(bsz, s, h, p).astype(x.dtype), final

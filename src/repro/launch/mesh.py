@@ -1,4 +1,4 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 A FUNCTION, not a module-level constant: importing this module never
 touches jax device state (jax locks the device count on first init, and
@@ -14,13 +14,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh (smoke tests use (1, 1); benches use host devices)."""
-    # axis_types only exists from jax 0.5; Auto is the default there anyway.
-    # 0.4.x compat shim: collapse to the axis_types call unconditionally
-    # when the jax floor moves to >= 0.6
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
+    """A mesh over ``devices``, by default the CPU devices.
+
+    Workload exports compile for the CPU on every host (see
+    :func:`repro.core.pipeline.export_workload`), so their meshes are CPU
+    meshes; a run on accelerators passes ``devices=jax.devices()``."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=jax.devices("cpu") if devices is None else devices)

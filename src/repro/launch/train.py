@@ -1,10 +1,12 @@
 """Training launcher: --arch <id> [--smoke] with checkpointing/restart.
 
-On real hardware this process runs once per host (jax.distributed); in
-this container it runs the same code path on the local device.
+Runs the config at its published widths on the process's default device
+(the TPU on a TPU host), with the config's own activation checkpointing
+unless ``--remat`` overrides it.  The llama3-1b step that ``chip_smoke.py``
+runs on one v5e::
 
-    PYTHONPATH=src python -m repro.launch.train --arch llama3-100m \
-        --steps 100 --ckpt /tmp/ckpt
+    PYTHONPATH=src python -m repro.launch.train --arch llama3-1b \
+        --seq 2048 --batch 1 --remat full --steps 8
 """
 from __future__ import annotations
 
@@ -22,6 +24,8 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--optimizer", default="adamw",
                     choices=["adamw", "adafactor"])
+    ap.add_argument("--remat", choices=["none", "full"], default=None,
+                    help="activation checkpointing (default: the config's)")
     ap.add_argument("--microbatch", type=int, default=0)
     ap.add_argument("--grad-compression", action="store_true")
     ap.add_argument("--ckpt", default=None)
@@ -30,12 +34,14 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs.base import RunConfig, ShapeConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import get_config, get_smoke_config
     from repro.train import train
 
+    enable_compile_cache()
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
-    if not args.smoke:
-        cfg = cfg.scaled(remat="none")  # single-host example scale
+    if args.remat:
+        cfg = cfg.scaled(remat=args.remat)
     run = RunConfig(
         model=cfg, shape=ShapeConfig("cli", args.seq, args.batch, "train"),
         learning_rate=args.lr, optimizer=args.optimizer,

@@ -193,13 +193,9 @@ def multihead_attention(q, k, v, args: AttnArgs, impl: str = "chunked",
                         chunk: int = 1024) -> jax.Array:
     if impl == "pallas":
         from ..kernels.flash_attention.ops import flash_attention
-        try:
-            return flash_attention(q, k, v, causal=args.causal,
-                                   window=args.window,
-                                   logit_cap=args.logit_cap,
-                                   q_offset=args.q_offset)
-        except Exception:
-            impl = "chunked"  # CPU path: fall back to the jnp recurrence
+        return flash_attention(q, k, v, causal=args.causal,
+                               window=args.window, logit_cap=args.logit_cap,
+                               q_offset=args.q_offset)
     if impl == "dense" or q.shape[2] == 1:
         return _dense_attention(q, k, v, args)
     if q.shape[2] <= chunk and k.shape[2] <= chunk:

@@ -29,31 +29,6 @@ def softcap(logits: jax.Array, cap: float) -> jax.Array:
     return cap * jnp.tanh(logits / cap)
 
 
-@jax.custom_vjp
-def barrier(x):
-    """Differentiable optimization_barrier.
-
-    jax < 0.5 has no differentiation rule for the primitive; this wrapper
-    barriers both the primal and the cotangents, which is what newer jax
-    does natively — per-layer region boundaries survive in both the
-    forward and backward segments of the export.
-
-    0.4.x compat shim: retire (use jax.lax.optimization_barrier directly)
-    when the repo's jax floor moves to >= 0.6."""
-    return jax.lax.optimization_barrier(x)
-
-
-def _barrier_fwd(x):
-    return jax.lax.optimization_barrier(x), None
-
-
-def _barrier_bwd(_, g):
-    return (jax.lax.optimization_barrier(g),)
-
-
-barrier.defvjp(_barrier_fwd, _barrier_bwd)
-
-
 def dense(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
           accum_f32: bool = True) -> jax.Array:
     """x:[..., in] @ w:[in, out]; accumulates in f32 on the MXU."""

@@ -11,7 +11,6 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from .common import barrier
 from .params import ParamSpec
 
 _STAGES = {
@@ -132,7 +131,7 @@ def resnet_forward(cfg: ResNetConfig, params: dict, images: jax.Array,
                                blk["proj_bn"])
             x = jax.nn.relu(y + identity)
             if cfg.block_barriers:
-                x = barrier(x)
+                x = jax.lax.optimization_barrier(x)
     x = x.mean(axis=(1, 2))
     logits = (x.astype(jnp.float32)
               @ params["head"].astype(jnp.float32))

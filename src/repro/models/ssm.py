@@ -68,12 +68,9 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
     Returns (y [B, S, H, P], final_state [B, H, P, N]).
     """
     if use_pallas:
-        try:
-            from ..kernels.ssd_scan.ops import ssd_scan
-            return ssd_scan(x, dt, a, b_in, c_in, chunk=chunk,
-                            initial_state=initial_state)
-        except Exception:
-            pass
+        from ..kernels.ssd_scan.ops import ssd_scan
+        return ssd_scan(x, dt, a, b_in, c_in, chunk=chunk,
+                        initial_state=initial_state)
     bsz, s, h, p = x.shape
     g, n = b_in.shape[2], b_in.shape[3]
     nc = s // chunk

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from .attention import (attention_specs, gqa_decode, gqa_forward, mla_decode,
                         mla_forward, mla_specs)
-from .common import (barrier, embedding_spec, norm_spec, rms_norm,
+from .common import (embedding_spec, norm_spec, rms_norm,
                      shard_act, softcap)
 from .mlp import (mlp_forward, mlp_specs, moe_aux_loss, moe_forward,
                   moe_forward_ep, moe_specs)
@@ -211,7 +211,7 @@ def _scan_layers(cfg: ModelConfig, params: dict, h: jax.Array,
             lp_i = jax.tree.map(lambda x: x[i], lp)
             h, _ = block(h, (lp_i, jnp.int32(i)))
             if cfg.layer_barriers:
-                h = barrier(h)
+                h = jax.lax.optimization_barrier(h)
         return h
 
     body = block

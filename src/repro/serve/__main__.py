@@ -86,7 +86,9 @@ def main(argv: list[str] | None = None) -> int:
         from . import faults
         os.environ[faults.ENV_PLAN] = args.fault_plan
 
+    from ..launch.compile_cache import enable_compile_cache
     from .server import PredictionServer, PredictionService
+    enable_compile_cache()
     service = PredictionService(cache_path=args.cache,
                                 systems=tuple(args.systems))
     for err in _portability_errors(service):

@@ -35,6 +35,11 @@ Failure handling, in order of escalation:
   store via the analytical (``roofline``) estimator with
   ``degraded: true`` instead of 5xx-ing or killing more workers.
 
+Workers run with ``JAX_PLATFORMS=cpu``: exports compile for the CPU on
+every host, and a chip belongs to one process.  A request that names an
+estimator holding the device (``profiling``) is refused with a 400; a
+single daemon serves it.
+
 ``/stats`` aggregates per-worker stats plus fleet counters (restarts,
 deaths, redispatches, degraded answers, breaker state) that
 ``tools/bench_check.py`` pins in CI.  See ``docs/robustness.md``.
@@ -57,7 +62,7 @@ from urllib.parse import urlsplit
 from .client import TIMEOUT_HEADER
 
 __all__ = ["FleetSupervisor", "WorkerHandle", "route_index",
-           "request_class"]
+           "request_class", "device_holding_kinds"]
 
 
 # ------------------------------ routing ------------------------------
@@ -87,6 +92,28 @@ def request_class(path: str, body: dict) -> tuple:
                 else body.get("spec_path"))
         return (path.lstrip("/"), str(name))
     return (path.lstrip("/"),)
+
+
+def device_holding_kinds(body: dict) -> list[str]:
+    """The estimator kinds a request names that run on the host's device
+    (``holds_device``): a ``/predict`` estimator, an inline or server-side
+    campaign spec's ``estimators``, a search spec's ``ladder``."""
+    from ..core.registry import ESTIMATORS
+
+    spec = body.get("spec")
+    if spec is None and body.get("spec_path"):
+        try:
+            with open(str(body["spec_path"])) as f:
+                spec = json.load(f)
+        except (OSError, ValueError):
+            spec = None          # the worker reports the unreadable spec
+    named = [body.get("estimator", "roofline")]
+    if isinstance(spec, dict):
+        named += list(spec.get("estimators", [])) + list(spec.get("ladder",
+                                                                  []))
+    kinds = {e.get("kind") if isinstance(e, dict) else e for e in named}
+    return sorted(k for k in kinds if isinstance(k, str) and k in ESTIMATORS
+                  and getattr(ESTIMATORS.get(k), "holds_device", False))
 
 
 # ------------------------------ workers ------------------------------
@@ -284,6 +311,10 @@ class FleetSupervisor:
         for p in self.preload:
             cmd += ["--preload", p]
         env = dict(os.environ)
+        # exports compile for the CPU on every host; a worker that also
+        # loaded the accelerator's runtime would fight its siblings for
+        # the one chip (device-holding estimators are refused up front)
+        env["JAX_PLATFORMS"] = "cpu"
         env["REPRO_FAULT_WORKER"] = str(idx)
         env["REPRO_FAULT_GENERATION"] = str(generation)
         if self.fault_plan:
@@ -607,6 +638,14 @@ def _make_handler(fleet: FleetSupervisor):
                 body = self._body()
             except (ValueError, OSError) as e:
                 self._json(400, {"error": f"bad request body: {e}"})
+                return
+            held = device_holding_kinds(body)
+            if held:
+                self._json(400, {"error": (
+                    f"estimator kind(s) {held} run on the host's device, "
+                    f"which one process owns; this fleet runs "
+                    f"{fleet.n} worker processes — serve them from a "
+                    "single daemon (--workers 1)")})
                 return
             try:
                 if path == "/predict":

@@ -1,6 +1,7 @@
 """Training loop: jitted pjit train_step + fault-tolerant outer loop."""
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass
 from functools import partial
@@ -115,6 +116,14 @@ def train_step_exports(cfg: ModelConfig, seq: int, batch: int, mesh=None,
     return jitted, (params_abs, opt_abs, batch_abs)
 
 
+def optimizer_config(run: RunConfig) -> OptimizerConfig:
+    """The optimizer :func:`train` builds for ``run``."""
+    return OptimizerConfig(
+        name=run.optimizer, learning_rate=run.learning_rate,
+        warmup_steps=run.warmup_steps,
+        weight_decay=run.weight_decay, grad_clip=run.grad_clip)
+
+
 @dataclass
 class TrainResult:
     steps: int
@@ -135,9 +144,7 @@ def train(run: RunConfig, *, mesh=None, num_steps: int = 20,
     the loop restores from the last committed checkpoint and continues
     (tested in tests/test_fault_tolerance.py)."""
     cfg = run.model
-    opt_cfg = OptimizerConfig(
-        name=run.optimizer, learning_rate=run.learning_rate,
-        weight_decay=run.weight_decay, grad_clip=run.grad_clip)
+    opt_cfg = optimizer_config(run)
     init_fn, _ = make_optimizer(opt_cfg)
     rules = rules or ShardingRules()
 
@@ -145,9 +152,6 @@ def train(run: RunConfig, *, mesh=None, num_steps: int = 20,
     key = jax.random.PRNGKey(run.seed)
     params = init_params(specs, key)
     if mesh is not None:
-        from .data import ShardedLoader  # placement path
-        from ..models.params import tree_paths, is_spec
-
         def place(subtree, spec):
             return jax.device_put(
                 subtree, param_sharding(spec.axes, mesh, rules))
@@ -155,6 +159,14 @@ def train(run: RunConfig, *, mesh=None, num_steps: int = 20,
                               is_leaf=lambda x: hasattr(x, "shape")
                               and not isinstance(x, dict))
     opt_state = init_fn(params, opt_cfg)
+    if mesh is not None:
+        # state not placed like a parameter (the step counter) is
+        # replicated over the mesh, not left on the first device
+        replicated = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec())
+        opt_state = jax.tree.map(
+            lambda x: x if isinstance(x.sharding, jax.sharding.NamedSharding)
+            else jax.device_put(x, replicated), opt_state)
 
     data_cfg = DataConfig(
         vocab_size=cfg.vocab_size, seq_len=run.shape.seq_len,
@@ -175,10 +187,18 @@ def train(run: RunConfig, *, mesh=None, num_steps: int = 20,
                 source.restore(data_state)
             start_step = step + 1
 
+    # on a mesh the step returns its state placed as it came in, so the
+    # second step reuses the first step's executable
+    state_shardings = None if mesh is None else (
+        jax.tree.map(lambda x: x.sharding, (params, opt_state)) + (None,))
     step_fn = jax.jit(make_train_step(
         cfg, opt_cfg, microbatch=run.microbatch,
         gradient_compression=run.gradient_compression),
-        donate_argnums=(0, 1))
+        donate_argnums=(0, 1), out_shardings=state_shardings)
+    # the step traces under the mesh, so the models' activation sharding
+    # constraints apply; without them the attention scan's buffers hold
+    # the global batch on every device
+    in_mesh = mesh if mesh is not None else contextlib.nullcontext()
 
     detector = StragglerDetector()
     losses: list[float] = []
@@ -192,7 +212,9 @@ def train(run: RunConfig, *, mesh=None, num_steps: int = 20,
             if failure_armed and step == inject_failure_at:
                 failure_armed = False
                 raise RuntimeError("injected node failure")
-            params, opt_state, metrics = step_fn(params, opt_state, batch)
+            with in_mesh:
+                params, opt_state, metrics = jax.block_until_ready(
+                    step_fn(params, opt_state, batch))
             loss = float(metrics["loss"])
             dt = time.perf_counter() - t0
             detector.observe(step, dt)

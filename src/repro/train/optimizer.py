@@ -92,11 +92,13 @@ def opt_state_abstract(specs, opt_name: str, mesh=None, rules=None):
 # --------------------------------------------------------------------------
 
 def adamw_init(params, cfg: OptimizerConfig):
+    """Zero moments placed like their parameters (sharded parameters get
+    sharded moments, not copies on one device)."""
     dt = jnp.dtype(cfg.state_dtype)
     return {
         "step": jnp.zeros((), jnp.int32),
-        "m": jax.tree.map(lambda p: jnp.zeros(p.shape, dt), params),
-        "v": jax.tree.map(lambda p: jnp.zeros(p.shape, dt), params),
+        "m": jax.tree.map(lambda p: jnp.zeros_like(p, dtype=dt), params),
+        "v": jax.tree.map(lambda p: jnp.zeros_like(p, dtype=dt), params),
     }
 
 
@@ -146,7 +148,7 @@ def adafactor_init(params, cfg: OptimizerConfig):
         if _factored(p.shape, cfg.min_dim_size_to_factor):
             return {"vr": jnp.zeros(p.shape[:-1], dt),
                     "vc": jnp.zeros((*p.shape[:-2], p.shape[-1]), dt)}
-        return {"v": jnp.zeros(p.shape, dt)}
+        return {"v": jnp.zeros_like(p, dtype=dt)}
 
     return {"step": jnp.zeros((), jnp.int32),
             "v": jax.tree.map(one, params,
