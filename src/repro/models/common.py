@@ -10,11 +10,12 @@ from .params import ParamSpec
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5,
              offset: float = 0.0) -> jax.Array:
-    dtype = x.dtype
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    y = xf * jax.lax.rsqrt(var + eps)
-    return (y * (offset + weight.astype(jnp.float32))).astype(dtype)
+    with jax.named_scope("norm"):
+        dtype = x.dtype
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        y = xf * jax.lax.rsqrt(var + eps)
+        return (y * (offset + weight.astype(jnp.float32))).astype(dtype)
 
 
 def activation(name: str):

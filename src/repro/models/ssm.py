@@ -171,39 +171,44 @@ def mamba2_forward(cfg: ModelConfig, p: dict, hidden: jax.Array,
     g, n = s_cfg.n_groups, s_cfg.d_state
     bsz, s, _ = hidden.shape
 
-    zxbcdt = dense(hidden, p["in_proj"])
+    with jax.named_scope("proj"):
+        zxbcdt = dense(hidden, p["in_proj"])
     z, xbc, dt_raw = jnp.split(zxbcdt, [di, di + di + 2 * g * n], axis=-1)
-    if decode:
-        # rolling conv state: [B, K-1, conv_ch]
-        conv_in = jnp.concatenate([conv_state, xbc], axis=1)
-        new_conv_state = conv_in[:, 1:]
-        k = p["conv_w"].shape[0]
-        xbc_conv = jnp.einsum("bkc,kc->bc", conv_in[:, -k:],
-                              p["conv_w"].astype(jnp.float32)) \
-            + p["conv_b"].astype(jnp.float32)
-        xbc_conv = jax.nn.silu(xbc_conv)[:, None].astype(hidden.dtype)
-    else:
-        xbc_conv = jax.nn.silu(
-            _causal_conv(xbc, p["conv_w"], p["conv_b"]))
-        new_conv_state = xbc[:, -(p["conv_w"].shape[0] - 1):]
+    with jax.named_scope("conv"):
+        if decode:
+            # rolling conv state: [B, K-1, conv_ch]
+            conv_in = jnp.concatenate([conv_state, xbc], axis=1)
+            new_conv_state = conv_in[:, 1:]
+            k = p["conv_w"].shape[0]
+            xbc_conv = jnp.einsum("bkc,kc->bc", conv_in[:, -k:],
+                                  p["conv_w"].astype(jnp.float32)) \
+                + p["conv_b"].astype(jnp.float32)
+            xbc_conv = jax.nn.silu(xbc_conv)[:, None].astype(hidden.dtype)
+        else:
+            xbc_conv = jax.nn.silu(
+                _causal_conv(xbc, p["conv_w"], p["conv_b"]))
+            new_conv_state = xbc[:, -(p["conv_w"].shape[0] - 1):]
 
     x_in, b_in, c_in = jnp.split(xbc_conv, [di, di + g * n], axis=-1)
     x_in = x_in.reshape(bsz, s, nh, s_cfg.head_dim)
     b_in = b_in.reshape(bsz, s, g, n)
     c_in = c_in.reshape(bsz, s, g, n)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
-                         + p["dt_bias"].astype(jnp.float32))
-    a = -jnp.exp(p["a_log"].astype(jnp.float32))
+    with jax.named_scope("ssd"):
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                             + p["dt_bias"].astype(jnp.float32))
+        a = -jnp.exp(p["a_log"].astype(jnp.float32))
 
-    if decode:
-        y, new_state = ssd_decode_step(x_in, dt, a, b_in, c_in, ssm_state)
-    else:
-        y, new_state = ssd_chunked(
-            x_in, dt, a, b_in, c_in, chunk=min(s_cfg.chunk_size, s),
-            initial_state=ssm_state,
-            use_pallas=cfg.attn_impl == "pallas")
-    y = y + x_in * p["d_skip"].astype(hidden.dtype)[None, None, :, None]
+        if decode:
+            y, new_state = ssd_decode_step(x_in, dt, a, b_in, c_in,
+                                           ssm_state)
+        else:
+            y, new_state = ssd_chunked(
+                x_in, dt, a, b_in, c_in, chunk=min(s_cfg.chunk_size, s),
+                initial_state=ssm_state,
+                use_pallas=cfg.attn_impl == "pallas")
+        y = y + x_in * p["d_skip"].astype(hidden.dtype)[None, None, :, None]
     y = y.reshape(bsz, s, di)
     y = rms_norm(y * jax.nn.silu(z), p["out_norm"], cfg.rms_eps)
-    out = dense(y, p["out_proj"])
+    with jax.named_scope("proj"):
+        out = dense(y, p["out_proj"])
     return out, new_state, new_conv_state

@@ -134,9 +134,10 @@ def _embed(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     if cfg.frontend == "stub":
         h = batch["embeds"].astype(jnp.dtype(cfg.dtype))
     else:
-        h = jnp.take(params["embed"], batch["tokens"], axis=0)
-        if cfg.tie_embeddings:
-            h = h * math.sqrt(cfg.d_model)
+        with jax.named_scope("vocab"):
+            h = jnp.take(params["embed"], batch["tokens"], axis=0)
+            if cfg.tie_embeddings:
+                h = h * math.sqrt(cfg.d_model)
     return shard_act(h, ("batch", "seq", "embed"))
 
 
@@ -230,12 +231,13 @@ def forward(cfg: ModelConfig, params: dict, batch: dict):
     positions = _positions(batch)
     mrope = batch.get("mrope_positions")
     h = _scan_layers(cfg, params, h, positions, mrope)
-    if cfg.loss_vocab_chunk > 0:
-        loss = chunked_cross_entropy(cfg, params, h, batch["targets"],
-                                     cfg.loss_vocab_chunk)
-        return loss, None
-    logits = _logits(cfg, params, h)
-    loss = cross_entropy(logits, batch["targets"])
+    with jax.named_scope("vocab"):
+        if cfg.loss_vocab_chunk > 0:
+            loss = chunked_cross_entropy(cfg, params, h, batch["targets"],
+                                         cfg.loss_vocab_chunk)
+            return loss, None
+        logits = _logits(cfg, params, h)
+        loss = cross_entropy(logits, batch["targets"])
     if cfg.moe is not None:
         # router aux loss on the mean hidden state (cheap proxy; per-layer
         # aux would need scan ys — tracked as beyond-paper TODO)

@@ -7,7 +7,7 @@ a v5e pod (see EXPERIMENTS.md §Dry-run memory table)."""
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -87,6 +87,16 @@ def opt_state_abstract(specs, opt_name: str, mesh=None, rules=None):
             "v": jax.tree.map(fac, specs, is_leaf=is_spec)}
 
 
+def _scoped(update):
+    """``update`` under the ``optimizer`` name scope: the clip and the
+    moments' update are named so in the compiled step and its trace."""
+    @wraps(update)
+    def run(*args, **kwargs):
+        with jax.named_scope("optimizer"):
+            return update(*args, **kwargs)
+    return run
+
+
 # --------------------------------------------------------------------------
 # AdamW
 # --------------------------------------------------------------------------
@@ -102,6 +112,7 @@ def adamw_init(params, cfg: OptimizerConfig):
     }
 
 
+@_scoped
 def adamw_update(params, grads, state, cfg: OptimizerConfig):
     step = state["step"] + 1
     lr = lr_schedule(cfg, step)
@@ -155,6 +166,7 @@ def adafactor_init(params, cfg: OptimizerConfig):
                               is_leaf=lambda x: hasattr(x, "shape"))}
 
 
+@_scoped
 def adafactor_update(params, grads, state, cfg: OptimizerConfig):
     step = state["step"] + 1
     lr = lr_schedule(cfg, step)
