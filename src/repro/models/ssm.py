@@ -46,10 +46,11 @@ def ssm_specs(cfg: ModelConfig, stacked: int = 0) -> dict:
     }
 
 
-def _segsum(x: jax.Array) -> jax.Array:
-    """Stable segment-sum: out[..., i, j] = sum_{k=j+1..i} x[..., k]."""
-    l = x.shape[-1]
-    cs = jnp.cumsum(x, axis=-1)
+def _segsum(cs: jax.Array) -> jax.Array:
+    """Segment sums from a cumulative sum ``cs`` of x along the last axis:
+    out[..., i, j] = sum_{k=j+1..i} x[..., k] = cs[..., i] - cs[..., j]
+    for j <= i, and -inf above the diagonal."""
+    l = cs.shape[-1]
     out = cs[..., :, None] - cs[..., None, :]
     mask = jnp.tril(jnp.ones((l, l), bool), k=0)
     return jnp.where(mask, out, -jnp.inf)
@@ -64,8 +65,12 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
     x:  [B, S, H, P]  (P = head dim)
     dt: [B, S, H]     (positive step sizes)
     a:  [H]           (negative decay rates)
-    b_in, c_in: [B, S, G, N]
+    b_in, c_in: [B, S, G, N]  (head h reads group h // (H / G))
     Returns (y [B, S, H, P], final_state [B, H, P, N]).
+
+    B and C are contracted once per group: the heads of a group share the
+    intra-chunk scores C·Bᵀ, the chunk states' B and the inter-chunk
+    output's C. Only the decay mask exp(segsum(dt·a)) is per head.
     """
     if use_pallas:
         from ..kernels.ssd_scan.ops import ssd_scan
@@ -78,29 +83,27 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
     hpg = h // g
     f32 = jnp.float32
 
-    # [B, C, L, ...] chunked views
-    xc = x.reshape(bsz, nc, chunk, h, p).astype(f32)
-    dtc = dt.reshape(bsz, nc, chunk, h).astype(f32)
+    # [B, C, L, ...] chunked views, heads split as [G, E] (E = H / G)
+    xc = x.reshape(bsz, nc, chunk, g, hpg, p).astype(f32)
+    dtc = dt.reshape(bsz, nc, chunk, g, hpg).astype(f32)
     bc = b_in.reshape(bsz, nc, chunk, g, n).astype(f32)
     cc = c_in.reshape(bsz, nc, chunk, g, n).astype(f32)
-    da = dtc * a.astype(f32)[None, None, None, :]         # [B,C,L,H]
+    da = dtc * a.astype(f32).reshape(g, hpg)              # [B,C,L,G,E]
     da_cs = jnp.cumsum(da, axis=2)                        # within-chunk cumsum
-    da_total = da_cs[:, :, -1]                            # [B,C,H]
-
-    # expand groups to heads for score contractions
-    bh = jnp.repeat(bc, hpg, axis=3)                      # [B,C,L,H,N]
-    ch = jnp.repeat(cc, hpg, axis=3)
+    da_total = da_cs[:, :, -1]                            # [B,C,G,E]
+    xdt = xc * dtc[..., None]                             # dt-weighted input
 
     # ---- intra-chunk (dual / attention-like) ----
-    lmat = jnp.exp(_segsum(da.transpose(0, 1, 3, 2)))     # [B,C,H,L,L]
-    scores = jnp.einsum("bclhn,bcshn->bchls", ch, bh)     # [B,C,H,L,S]
-    scores = scores * lmat
-    xdt = xc * dtc[..., None]                             # dt-weighted input
-    y_diag = jnp.einsum("bchls,bcshp->bclhp", scores, xdt)
+    # [B,C,G,E,L,L], from the cumsum the chunk states use
+    lmat = jnp.exp(_segsum(da_cs.transpose(0, 1, 3, 4, 2)))
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc)         # [B,C,G,L,S]
+    scores = cb[:, :, :, None] * lmat                     # [B,C,G,E,L,S]
+    y_diag = jnp.einsum("bcgels,bcsgep->bclgep", scores, xdt)
 
     # ---- chunk states ----
-    decay_to_end = jnp.exp(da_total[:, :, None, :] - da_cs)  # [B,C,L,H]
-    states = jnp.einsum("bclhn,bclh,bclhp->bchpn", bh, decay_to_end * dtc, xc)
+    decay_to_end = jnp.exp(da_total[:, :, None] - da_cs)  # [B,C,L,G,E]
+    states = jnp.einsum("bclgn,bclgep->bcgepn", bc,
+                        xdt * decay_to_end[..., None])
 
     # ---- inter-chunk recurrence ----
     def step(carry, inp):
@@ -113,13 +116,14 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
             else initial_state.astype(f32))
     final, prev_states = jax.lax.scan(
         step, init,
-        (states.transpose(1, 0, 2, 3, 4), da_total.transpose(1, 0, 2)))
-    prev_states = prev_states.transpose(1, 0, 2, 3, 4)    # [B,C,H,P,N]
+        (states.reshape(bsz, nc, h, p, n).transpose(1, 0, 2, 3, 4),
+         da_total.reshape(bsz, nc, h).transpose(1, 0, 2)))
+    prev_states = prev_states.transpose(1, 0, 2, 3, 4).reshape(
+        bsz, nc, g, hpg, p, n)                            # [B,C,G,E,P,N]
 
     # ---- inter-chunk contribution ----
-    decay_from_start = jnp.exp(da_cs)                     # [B,C,L,H]
-    y_off = jnp.einsum("bclhn,bchpn,bclh->bclhp",
-                       ch, prev_states, decay_from_start)
+    y_off = (jnp.einsum("bclgn,bcgepn->bclgep", cc, prev_states)
+             * jnp.exp(da_cs)[..., None])
 
     y = (y_diag + y_off).reshape(bsz, s, h, p)
     return y.astype(x.dtype), final

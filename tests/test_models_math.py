@@ -142,20 +142,64 @@ class TestSSM:
         y = _causal_conv(x, w, b)
         assert float(jnp.abs(y[0, :5]).sum()) == 0.0  # nothing before t=8-3
 
-    @settings(max_examples=10, deadline=None)
-    @given(seed=st.integers(0, 1000))
-    def test_ssd_chunked_matches_sequential(self, seed):
+    @staticmethod
+    def _ssd_inputs(seed, h, g):
+        b, s, p, n = 1, 64, 8, 4
         ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-        b, s, h, p, g, n = 1, 64, 2, 8, 1, 4
         x = jax.random.normal(ks[0], (b, s, h, p), jnp.float32)
         dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)))
         a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.5)
         bi = jax.random.normal(ks[3], (b, s, g, n), jnp.float32)
         ci = jax.random.normal(ks[4], (b, s, g, n), jnp.float32)
-        y, st_ = ssd_chunked(x, dt, a, bi, ci, chunk=16)
-        yr, sr = ssd_ref(x, dt, a, bi, ci)
+        return x, dt, a, bi, ci
+
+    @pytest.mark.parametrize("h,g", [(2, 1), (4, 1), (4, 2), (4, 4)])
+    @settings(max_examples=10, deadline=None)
+    @given(seed=st.integers(0, 1000))
+    def test_ssd_chunked_matches_sequential(self, seed, h, g):
+        x, dt, a, bi, ci = self._ssd_inputs(seed, h, g)
+        y, st_ = jax.jit(ssd_chunked, static_argnames="chunk")(
+            x, dt, a, bi, ci, chunk=16)
+        yr, sr = jax.jit(ssd_ref)(x, dt, a, bi, ci)
         np.testing.assert_allclose(y, yr, atol=3e-3, rtol=3e-3)
         np.testing.assert_allclose(st_, sr, atol=3e-3, rtol=3e-3)
+
+    @pytest.mark.parametrize("h,g", [(2, 1), (4, 1), (4, 2), (4, 4)])
+    def test_ssd_chunked_grads_match_sequential(self, h, g):
+        """Gradients w.r.t. x, dt, a, B and C match the recurrence's."""
+        args = self._ssd_inputs(7, h, g)
+        ky, ks = jax.random.split(jax.random.PRNGKey(11))
+        wy = jax.random.normal(ky, args[0].shape)
+        ws = jax.random.normal(ks, (1, h, 8, 4))          # [B,H,P,N]
+
+        def loss(fn):
+            def f(*a):
+                y, st_ = fn(*a)
+                return jnp.sum(y * wy) + jnp.sum(st_ * ws)
+            return jax.jit(jax.grad(f, argnums=range(5)))(*args)
+
+        got = loss(lambda *a: ssd_chunked(*a, chunk=16))
+        want = loss(ssd_ref)
+        for gc, gr in zip(got, want):
+            scale = float(jnp.max(jnp.abs(gr)))
+            np.testing.assert_allclose(gc, gr, atol=3e-3 * scale, rtol=3e-3)
+
+    def test_score_flops_do_not_scale_with_heads(self):
+        """C·Bᵀ is contracted once per group: with one group, the lowered
+        program does far fewer FLOPs than with a group per head."""
+        from repro.core.ir import parse, program_cost
+        h, p, n, s, chunk = 8, 4, 64, 128, 64
+
+        def flops_for(g):
+            f32 = jnp.float32
+            shapes = [(1, s, h, p), (1, s, h), (h,), (1, s, g, n),
+                      (1, s, g, n)]
+            txt = jax.jit(lambda *a: ssd_chunked(*a, chunk=chunk)).lower(
+                *(jax.ShapeDtypeStruct(sh, f32) for sh in shapes)).as_text()
+            return program_cost(parse(txt)).flops
+
+        # head-repeated B and C would cost the same at g = 1 as at g = h
+        assert flops_for(1) < 0.5 * flops_for(h)
 
 
 class TestNumerics:
