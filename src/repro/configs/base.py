@@ -73,7 +73,15 @@ class ModelConfig:
     moe: MoEConfig | None = None
     mla: MLAConfig | None = None
     ssm: SSMConfig | None = None
-    # hybrid (zamba2-style): 1 shared attention block every N ssm layers
+    # hybrid (Zamba2, arXiv:2411.15242): every layer is a Mamba2 layer; at
+    # each of ``hybrid_layer_ids`` the k-th invocation of shared block
+    # k % num_mem_blocks runs first, with its own LoRA adapter (rank
+    # ``adapter_rank``) on the MLP and its own output ``linear``
+    hybrid_layer_ids: list[int] = field(default_factory=list)
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
+    # not read by the model: a regular period of shared attention blocks,
+    # compared by the benchmark's configuration files (0 for every model)
     hybrid_attn_every: int = 0
     # norm / misc
     rms_eps: float = 1e-5
@@ -103,6 +111,16 @@ class ModelConfig:
     #                                before W_o, so the math is EXACT and
     #                                pad-row gradients are identically zero
 
+    def __post_init__(self):
+        # a list, so that it equals the list a JSON configuration states
+        ids = list(self.hybrid_layer_ids)
+        object.__setattr__(self, "hybrid_layer_ids", ids)
+        if self.family == "hybrid":
+            assert self.num_mem_blocks >= 1, self.num_mem_blocks
+            assert ids == sorted(set(ids)) and ids and \
+                0 <= ids[0] and ids[-1] < self.num_layers, \
+                (ids, self.num_layers)
+
     @property
     def resolved_head_dim(self) -> int:
         if self.head_dim:
@@ -131,21 +149,22 @@ class ModelConfig:
             s = self.ssm or SSMConfig()
             di = s.d_inner(d)
             nh = s.n_heads(d)
+            conv_ch = di + 2 * s.n_groups * s.d_state
             per = (d * (2 * di + 2 * s.n_groups * s.d_state + nh)  # in_proj
-                   + s.d_conv * (di + 2 * s.n_groups * s.d_state)  # conv
+                   + (s.d_conv + 1) * conv_ch                      # conv, bias
                    + di * d                                        # out_proj
-                   + 2 * nh + d)                                   # A, D, norm
-            n_ssm = L
-            attn_per = 0
-            if self.family == "hybrid" and self.hybrid_attn_every:
-                kvh = self.num_kv_heads
-                attn_per = (d * self.num_heads * hd + 2 * d * kvh * hd
-                            + self.num_heads * hd * d + d * self.d_ff * 3)
-                total += attn_per  # shared block counted once
-                active += attn_per
-            total += n_ssm * per
-            active += n_ssm * per
-            return total, active
+                   + 3 * nh + di + d)          # A, dt bias, D; norms
+            total += L * per + d                          # final norm
+            if self.family == "hybrid":
+                f, r, k = self.d_ff, self.adapter_rank, \
+                    len(self.hybrid_layer_ids)
+                shared = (2 * d * self.num_heads * hd             # q
+                          + 2 * 2 * d * self.num_kv_heads * hd    # k, v
+                          + self.num_heads * hd * d               # o
+                          + 3 * d * f + 2 * d + d)                # mlp, norms
+                invocation = d * d + d * r + r * 2 * f    # linear, adapter
+                total += self.num_mem_blocks * shared + k * invocation
+            return total, total
         kvh = self.num_kv_heads
         if self.mla is not None:
             m = self.mla

@@ -1,9 +1,12 @@
 """Attention: GQA (with SWA / local-global / softcap / bias) and MLA.
 
-Three implementations behind one interface:
+Four implementations behind one interface:
   * dense   — materialized [Sq, Skv] scores (small shapes, oracle)
   * chunked — online-softmax scan over KV blocks (pure JAX flash attention;
               memory O(Sq · block) — required for 32k prefill)
+  * blocked — causal self-attention in query blocks, each over the keys up
+              to its end and recomputed in the backward pass (training at
+              long rows: one block's scores live at a time)
   * pallas  — TPU kernel (repro.kernels.flash_attention), same math
 
 Decode (Sq == 1) always uses the dense path over the KV cache; with a
@@ -13,6 +16,7 @@ all-reduce pair of flash-decoding.
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -30,10 +34,14 @@ NEG_INF = -2.0e38
 # parameter specs
 # --------------------------------------------------------------------------
 
-def attention_specs(cfg: ModelConfig, stacked: int = 0) -> dict:
-    """GQA projection specs; ``stacked``>0 prepends a layer axis (for scan)."""
+def attention_specs(cfg: ModelConfig, stacked: int = 0,
+                    d_in: int = 0) -> dict:
+    """GQA projection specs; ``stacked``>0 prepends a layer axis (for scan).
+    ``d_in`` is the input width where it is not ``d_model`` (Zamba2's
+    shared attention reads 2·d_model and writes d_model)."""
     d, h, kv, hd = (cfg.d_model, cfg.num_heads + cfg.pad_heads,
                     cfg.num_kv_heads, cfg.resolved_head_dim)
+    d_in = d_in or d
     if cfg.pad_heads:
         assert h % kv == 0, (h, kv)
     dt = cfg.dtype
@@ -45,9 +53,11 @@ def attention_specs(cfg: ModelConfig, stacked: int = 0) -> dict:
         return ParamSpec(shape, axes, dtype=dt, **kw)
 
     specs = {
-        "wq": p((d, h, hd), ("embed", "heads", "qk_dim"), init="scaled"),
-        "wk": p((d, kv, hd), ("embed", "kv_heads", "qk_dim"), init="scaled"),
-        "wv": p((d, kv, hd), ("embed", "kv_heads", "v_dim"), init="scaled"),
+        "wq": p((d_in, h, hd), ("embed", "heads", "qk_dim"), init="scaled"),
+        "wk": p((d_in, kv, hd), ("embed", "kv_heads", "qk_dim"),
+                init="scaled"),
+        "wv": p((d_in, kv, hd), ("embed", "kv_heads", "v_dim"),
+                init="scaled"),
         "wo": p((h, hd, d), ("heads", "v_dim", "embed"), init="scaled"),
     }
     if cfg.qkv_bias:
@@ -121,6 +131,15 @@ class AttnArgs(NamedTuple):
     window: int = 0              # >0: sliding window
     logit_cap: float = 0.0
     q_offset: int = 0            # absolute position of q[0] (decode/prefill)
+    score_dim: float = 0         # >0: scores over sqrt(score_dim), not
+    #                              sqrt(head_dim)
+
+
+def score_dim(cfg: ModelConfig) -> float:
+    """Zamba2 scales its shared attention's scores by (head_dim / 2)^-0.5
+    (its heads read the concatenation of two streams); 0 elsewhere, for
+    the usual head_dim^-0.5."""
+    return cfg.resolved_head_dim / 2 if cfg.family == "hybrid" else 0
 
 
 def _dense_attention(q, k, v, args: AttnArgs) -> jax.Array:
@@ -131,7 +150,8 @@ def _dense_attention(q, k, v, args: AttnArgs) -> jax.Array:
     group = hq // hkv
     qg = q.reshape(b, hkv, group, sq, dh)
     scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) / math.sqrt(dh)
+                        k.astype(jnp.float32)) / math.sqrt(
+                            args.score_dim or dh)
     scores = softcap(scores, args.logit_cap)
     q_idx = jnp.arange(sq) + args.q_offset
     k_idx = jnp.arange(skv)
@@ -156,7 +176,7 @@ def _chunked_attention(q, k, v, args: AttnArgs, chunk: int) -> jax.Array:
     kc = k.reshape(b, hkv, n_chunks, chunk, dh).transpose(2, 0, 1, 3, 4)
     vc = v.reshape(b, hkv, n_chunks, chunk, dv).transpose(2, 0, 1, 3, 4)
     qg = (q.reshape(b, hkv, group, sq, dh).astype(jnp.float32)
-          / math.sqrt(dh))
+          / math.sqrt(args.score_dim or dh))
     q_idx = jnp.arange(sq) + args.q_offset
 
     def body(carry, inputs):
@@ -189,6 +209,22 @@ def _chunked_attention(q, k, v, args: AttnArgs, chunk: int) -> jax.Array:
     return out.reshape(b, hq, sq, dv).astype(q.dtype)
 
 
+def _blocked_attention(q, k, v, args: AttnArgs, block: int) -> jax.Array:
+    """Causal self-attention in query blocks of ``block``: block i attends
+    the keys up to its own end (no work above the diagonal's blocks) and is
+    recomputed in the backward pass, so one block's scores are live."""
+    sq = q.shape[2]
+    if not args.causal or args.q_offset or k.shape[2] != sq:
+        raise ValueError("blocked attention is causal self-attention")
+    outs = []
+    for lo in range(0, sq, block):
+        hi = min(lo + block, sq)
+        one = jax.checkpoint(partial(_dense_attention,
+                                     args=args._replace(q_offset=lo)))
+        outs.append(one(q[:, :, lo:hi], k[:, :, :hi], v[:, :, :hi]))
+    return jnp.concatenate(outs, axis=2)
+
+
 def multihead_attention(q, k, v, args: AttnArgs, impl: str = "chunked",
                         chunk: int = 1024) -> jax.Array:
     if impl == "pallas":
@@ -200,6 +236,8 @@ def multihead_attention(q, k, v, args: AttnArgs, impl: str = "chunked",
         return _dense_attention(q, k, v, args)
     if q.shape[2] <= chunk and k.shape[2] <= chunk:
         return _dense_attention(q, k, v, args)
+    if impl == "blocked":
+        return _blocked_attention(q, k, v, args, chunk)
     return _chunked_attention(q, k, v, args, chunk)
 
 
@@ -228,8 +266,11 @@ def _head_mask(cfg: ModelConfig, out: jax.Array) -> jax.Array:
 
 def gqa_forward(cfg: ModelConfig, p: dict, x: jax.Array,
                 positions: jax.Array, *, layer_window: int = 0,
-                mrope_positions: jax.Array | None = None) -> jax.Array:
-    """Full-sequence GQA for train/prefill. x: [B, S, d]."""
+                mrope_positions: jax.Array | None = None,
+                return_kv: bool = False):
+    """Full-sequence GQA for train/prefill. x: [B, S, d].  With
+    ``return_kv`` also the keys (after rope) and values, [B, S, kv, hd],
+    as a decode cache holds them."""
     b, s, d = x.shape
     h, kv, hd = (cfg.num_heads + cfg.pad_heads, cfg.num_kv_heads,
                  cfg.resolved_head_dim)
@@ -247,14 +288,16 @@ def gqa_forward(cfg: ModelConfig, p: dict, x: jax.Array,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     args = AttnArgs(causal=cfg.causal, window=layer_window,
-                    logit_cap=cfg.attn_logit_softcap)
+                    logit_cap=cfg.attn_logit_softcap,
+                    score_dim=score_dim(cfg))
     out = multihead_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), args, impl=cfg.attn_impl,
         chunk=cfg.attn_chunk)
     out = out.transpose(0, 2, 1, 3)                      # [B, S, H, hd]
     out = _head_mask(cfg, out)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    return (y, k, v) if return_kv else y
 
 
 def gqa_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache_k: jax.Array,
@@ -298,7 +341,7 @@ def gqa_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache_k: jax.Array,
     group = hq // hkv
     qg = qt.reshape(b, hkv, group, 1, -1).astype(jnp.float32)
     scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kt.astype(jnp.float32))
-    scores = scores / math.sqrt(qt.shape[-1])
+    scores = scores / math.sqrt(score_dim(cfg) or qt.shape[-1])
     scores = softcap(scores, cfg.attn_logit_softcap)
     slot_idx = jnp.arange(s_max)
     if ring:

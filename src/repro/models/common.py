@@ -19,7 +19,8 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5,
 
 
 def activation(name: str):
-    return {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
+    return {"silu": jax.nn.silu,
+            "gelu": lambda x: jax.nn.gelu(x, approximate=False),
             "gelu_tanh": lambda x: jax.nn.gelu(x, approximate=True),
             "relu": jax.nn.relu}[name]
 

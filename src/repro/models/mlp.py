@@ -38,12 +38,31 @@ def mlp_specs(cfg: ModelConfig, stacked: int = 0, d_ff: int | None = None,
     }
 
 
+def adapter_specs(cfg: ModelConfig, stacked: int) -> dict:
+    """Zamba2's per-invocation LoRA on the shared MLP's gate_up: ``lora_a``
+    [d, r] then ``lora_b`` [r, 2·d_ff], gate half first."""
+    d, r, f, dt = cfg.d_model, cfg.adapter_rank, cfg.d_ff, cfg.dtype
+    return {
+        "lora_a": ParamSpec((stacked, d, r), ("layers", "embed", "lora"),
+                            init="scaled", dtype=dt),
+        "lora_b": ParamSpec((stacked, r, 2 * f), ("layers", "lora", "mlp"),
+                            init="scaled", dtype=dt),
+    }
+
+
 def mlp_forward(cfg: ModelConfig, p: dict, x: jax.Array,
-                suffix: str = "") -> jax.Array:
+                suffix: str = "", adapter: dict | None = None) -> jax.Array:
+    """Gated MLP; ``adapter`` (one invocation's ``lora_a``/``lora_b``, see
+    :func:`adapter_specs`) adds its low-rank term to the gate and up
+    projections before the activation."""
     act = activation(cfg.act)
-    g = act(dense(x, p[f"w_gate{suffix}"]))
+    g = dense(x, p[f"w_gate{suffix}"])
     u = dense(x, p[f"w_up{suffix}"])
-    return dense(g * u, p[f"w_down{suffix}"])
+    if adapter is not None:
+        ga, ua = jnp.split(dense(dense(x, adapter["lora_a"]),
+                                 adapter["lora_b"]), 2, axis=-1)
+        g, u = g + ga, u + ua
+    return dense(act(g) * u, p[f"w_down{suffix}"])
 
 
 # --------------------------------------------------------------------------

@@ -15,7 +15,7 @@ from ..configs.base import ModelConfig, ShapeConfig
 ARCH_IDS = [
     "mamba2-370m", "deepseek-67b", "stablelm-12b", "qwen2.5-32b",
     "gemma2-27b", "zamba2-2.7b", "deepseek-v3-671b", "mixtral-8x22b",
-    "hubert-xlarge", "qwen2-vl-7b",
+    "hubert-xlarge", "qwen2-vl-7b", "zamba2-7b",
 ]
 # paper-reproduction workload families (not part of the 40-cell matrix)
 EXTRA_IDS = ["llama3-100m", "llama3-500m", "llama3-1b", "llama3-3b",

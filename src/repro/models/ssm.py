@@ -159,6 +159,19 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return (out + b.astype(jnp.float32)).astype(x.dtype)
 
 
+def gated_rms_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int,
+                   eps: float) -> jax.Array:
+    """Mamba-2's gated norm: ``y * silu(z)`` RMS-normalised over each of
+    ``groups`` groups of channels on its own (mamba_ssm's and Zamba2's
+    ``group_size = d_inner / n_groups``), times ``w``; y, z: [..., d_inner].
+    One group is the norm over all channels."""
+    *lead, di = y.shape
+    split = lambda t: t.reshape(*lead, groups, di // groups)
+    out = rms_norm(split(y * jax.nn.silu(z)),
+                   w.reshape(groups, di // groups), eps)
+    return out.reshape(*lead, di)
+
+
 def mamba2_forward(cfg: ModelConfig, p: dict, hidden: jax.Array,
                    ssm_state: jax.Array | None = None,
                    conv_state: jax.Array | None = None,
@@ -211,8 +224,8 @@ def mamba2_forward(cfg: ModelConfig, p: dict, hidden: jax.Array,
                 initial_state=ssm_state,
                 use_pallas=cfg.attn_impl == "pallas")
         y = y + x_in * p["d_skip"].astype(hidden.dtype)[None, None, :, None]
-    y = y.reshape(bsz, s, di)
-    y = rms_norm(y * jax.nn.silu(z), p["out_norm"], cfg.rms_eps)
+    y = gated_rms_norm(y.reshape(bsz, s, di), z, p["out_norm"], g,
+                       cfg.rms_eps)
     with jax.named_scope("proj"):
         out = dense(y, p["out_proj"])
     return out, new_state, new_conv_state

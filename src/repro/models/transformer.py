@@ -5,9 +5,17 @@ Public entry points (all pure functions of (cfg, params, batch)):
 
   model_specs(cfg)                 -> ParamSpec tree
   forward(cfg, params, batch)      -> (loss, logits)      [train/eval]
-  prefill(cfg, params, batch)      -> (logits, cache)     [inference prefill]
+  prefill(cfg, params, batch)      -> last-token logits   [inference prefill]
+  prefill(cfg, params, batch, max_len) -> (logits, cache) [hybrid]
   decode_step(cfg, params, cache, batch) -> (logits, cache)
   init_cache_specs(cfg, batch, max_len)  -> cache ParamSpec tree
+
+The hybrid family is Zamba2 (arXiv:2411.15242, as transformers'
+``modeling_zamba2`` writes it): every layer is a Mamba2 layer
+``h <- h + Mamba_i(norm(h))``; at hybrid layer i, invocation k of shared
+block b = k mod ``num_mem_blocks`` computes
+``T = linear_k(MLP_b(norm(Attn_b(norm(concat(h, e)))), adapter_k))`` from
+the unscaled embedding e, and the layer is ``h <- h + Mamba_i(norm(h + T))``.
 """
 from __future__ import annotations
 
@@ -20,10 +28,10 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from .attention import (attention_specs, gqa_decode, gqa_forward, mla_decode,
                         mla_forward, mla_specs)
-from .common import (embedding_spec, norm_spec, rms_norm,
+from .common import (dense, embedding_spec, norm_spec, rms_norm,
                      shard_act, softcap)
-from .mlp import (mlp_forward, mlp_specs, moe_aux_loss, moe_forward,
-                  moe_forward_ep, moe_specs)
+from .mlp import (adapter_specs, mlp_forward, mlp_specs, moe_aux_loss,
+                  moe_forward, moe_forward_ep, moe_specs)
 from .params import ParamSpec
 from .ssm import mamba2_forward, ssm_specs
 
@@ -42,7 +50,7 @@ def _layer_specs(cfg: ModelConfig, stacked: int) -> dict:
                              init="ones", dtype=dt)
         return ParamSpec(shape, axes, init="ones", dtype=dt)
 
-    if cfg.family == "ssm":
+    if cfg.family in ("ssm", "hybrid"):
         return {"ssm": ssm_specs(cfg, stacked),
                 "ln": n((cfg.d_model,), ("norm",))}
     specs: dict = {"ln1": n((cfg.d_model,), ("norm",)),
@@ -68,23 +76,25 @@ def model_specs(cfg: ModelConfig) -> dict:
         specs["lm_head"] = ParamSpec((cfg.d_model, cfg.vocab_size),
                                      ("embed", "vocab"), init="scaled",
                                      dtype=dt)
+    specs["layers"] = _layer_specs(cfg, stacked=cfg.num_layers)
     if cfg.family == "hybrid":
-        every = cfg.hybrid_attn_every
-        n_super = cfg.num_layers // every
-        specs["layers"] = {
-            "ssm": ssm_specs(cfg, stacked=n_super * every),
-            "ln": ParamSpec((n_super * every, cfg.d_model),
-                            ("layers", "norm"), init="ones", dtype=dt),
+        d, m = cfg.d_model, cfg.num_mem_blocks
+        k = len(cfg.hybrid_layer_ids)
+        # the shared blocks, stacked on ``num_mem_blocks``, and what each
+        # invocation has of its own
+        specs["shared"] = {
+            "ln_attn": ParamSpec((m, 2 * d), ("layers", "norm"),
+                                 init="ones", dtype=dt),
+            "attn": attention_specs(cfg, stacked=m, d_in=2 * d),
+            "ln_mlp": ParamSpec((m, d), ("layers", "norm"), init="ones",
+                                dtype=dt),
+            "mlp": mlp_specs(cfg, stacked=m),
         }
-        # one SHARED attention block (Zamba2): reused by every super-block
-        specs["shared_attn"] = {
-            "attn": attention_specs(cfg, stacked=0),
-            "ln1": norm_spec(cfg.d_model, dt),
-            "ln2": norm_spec(cfg.d_model, dt),
-            "mlp": mlp_specs(cfg, stacked=0),
+        specs["hybrid"] = {
+            "linear": ParamSpec((k, d, d), ("layers", "embed", "embed"),
+                                init="scaled", dtype=dt),
+            **adapter_specs(cfg, stacked=k),
         }
-    else:
-        specs["layers"] = _layer_specs(cfg, stacked=cfg.num_layers)
     return specs
 
 
@@ -126,6 +136,109 @@ def ssm_block(cfg: ModelConfig, lp: dict, h: jax.Array):
     return h + shard_act(y, ("batch", "seq", "embed"))
 
 
+def _mamba_layer(cfg: ModelConfig, lp: dict, h: jax.Array, t, states):
+    """A hybrid stack's Mamba2 layer ``h + Mamba(norm(h + t))``, ``t`` the
+    shared block's output at a hybrid layer (None elsewhere).  ``states``
+    is the layer's (ssm, conv) cache in decode, None over a sequence.
+    Returns (h, (ssm_state, conv_state))."""
+    x = rms_norm(h if t is None else h + t, lp["ln"], cfg.rms_eps)
+    if states is None:
+        y, ss, cs = mamba2_forward(cfg, lp["ssm"], x)
+    else:
+        y, ss, cs = mamba2_forward(cfg, lp["ssm"], x, ssm_state=states[0],
+                                   conv_state=states[1], decode=True)
+    return h + shard_act(y, ("batch", "seq", "embed")), (ss, cs)
+
+
+def _hybrid_layer(cfg: ModelConfig, sp: dict, inv: dict, lp: dict,
+                  h: jax.Array, e: jax.Array, positions, states, kv,
+                  index):
+    """Hybrid layer: the shared block ``sp`` with invocation ``inv``'s
+    adapter and linear, then the layer's Mamba2.  ``kv`` is the
+    invocation's (k, v) cache in decode (at ``index``), None over a
+    sequence.  Returns (h, states, (k, v))."""
+    x = rms_norm(jnp.concatenate([h, e], axis=-1), sp["ln_attn"],
+                 cfg.rms_eps)
+    with jax.named_scope("attn"):
+        if kv is None:
+            y, k, v = gqa_forward(cfg, sp["attn"], x, positions,
+                                  return_kv=True)
+        else:
+            y, k, v = gqa_decode(cfg, sp["attn"], x, *kv, index)
+    x = rms_norm(y, sp["ln_mlp"], cfg.rms_eps)
+    with jax.named_scope("mlp"):
+        t = dense(mlp_forward(cfg, sp["mlp"], x, adapter=inv),
+                  inv["linear"])
+    h, states = _mamba_layer(cfg, lp, h, t, states)
+    return h, states, (k, v)
+
+
+def _hybrid_layers(cfg: ModelConfig, params: dict, h: jax.Array,
+                   e: jax.Array, positions, cache: dict | None = None,
+                   keep: bool = False):
+    """Zamba2's stack over ``h``, with ``e`` the unscaled embedding.
+
+    Runs of Mamba2 layers between hybrid layers are scans over their slice
+    of the stacked layers; each hybrid layer is called on its own, reading
+    its shared block's weights (no copy per invocation).  Each layer is
+    one checkpoint under ``remat == "full"``.  Over a sequence (``cache``
+    None) returns (h, None), or with ``keep`` (h, the cache a decode
+    continues from, less ``index``); in decode, (h, the updated cache)."""
+    lp = params["layers"]
+    ids = cfg.hybrid_layer_ids
+    decode = cache is not None
+    index = cache["index"] if decode else None
+    states_out = keep or decode
+    cut = lambda tree, lo, hi: jax.tree.map(lambda x: x[lo:hi], tree)
+    one = lambda tree, i: jax.tree.map(lambda x: x[i], tree)
+    remat = jax.checkpoint if cfg.remat == "full" else (lambda f: f)
+
+    def plain(c, xs):
+        p, st = xs
+        c, st = _mamba_layer(cfg, p, c, None, st)
+        return c, (st if states_out else None)
+
+    def layer_states(lo, hi):
+        if not decode:
+            return None
+        return (cache["ssm_state"][lo:hi], cache["conv_state"][lo:hi])
+
+    ssm_out, conv_out, k_out, v_out = [], [], [], []
+
+    def run(c, lo, hi):
+        if hi > lo:
+            c, st = jax.lax.scan(remat(plain), c,
+                                 (cut(lp, lo, hi), layer_states(lo, hi)),
+                                 unroll=not cfg.scan_layers)
+            if states_out:
+                ssm_out.append(st[0])
+                conv_out.append(st[1])
+        return c
+
+    start = 0
+    for k, i in enumerate(ids):
+        h = run(h, start, i)
+        sp = one(params["shared"], k % cfg.num_mem_blocks)
+        st = (cache["ssm_state"][i], cache["conv_state"][i]) if decode \
+            else None
+        kv = (cache["k"][k], cache["v"][k]) if decode else None
+        h, st, (ck, cv) = remat(partial(_hybrid_layer, cfg))(
+            sp, one(params["hybrid"], k), one(lp, i), h, e, positions, st,
+            kv, index)
+        if states_out:
+            ssm_out.append(st[0][None])
+            conv_out.append(st[1][None])
+            k_out.append(ck)
+            v_out.append(cv)
+        start = i + 1
+    h = run(h, start, cfg.num_layers)
+    if not states_out:
+        return h, None
+    return h, {"ssm_state": jnp.concatenate(ssm_out),
+               "conv_state": jnp.concatenate(conv_out),
+               "k": jnp.stack(k_out), "v": jnp.stack(v_out)}
+
+
 # --------------------------------------------------------------------------
 # embedding / head
 # --------------------------------------------------------------------------
@@ -136,7 +249,8 @@ def _embed(cfg: ModelConfig, params: dict, batch: dict) -> jax.Array:
     else:
         with jax.named_scope("vocab"):
             h = jnp.take(params["embed"], batch["tokens"], axis=0)
-            if cfg.tie_embeddings:
+            # Zamba2 does not scale its (tied) embedding
+            if cfg.tie_embeddings and cfg.family != "hybrid":
                 h = h * math.sqrt(cfg.d_model)
     return shard_act(h, ("batch", "seq", "embed"))
 
@@ -167,32 +281,9 @@ def _positions(batch: dict) -> jax.Array:
 def _scan_layers(cfg: ModelConfig, params: dict, h: jax.Array,
                  positions: jax.Array, mrope_positions=None) -> jax.Array:
     lp = params["layers"]
-
     if cfg.family == "hybrid":
-        every = cfg.hybrid_attn_every
-        n_super = cfg.num_layers // every
-        stacked = jax.tree.map(
-            lambda x: x.reshape(n_super, every, *x.shape[1:]), lp)
-        shared = params["shared_attn"]
-
-        def super_block(carry, xs):
-            hh = carry
-
-            def inner(c, xp):
-                x = rms_norm(c, xp["ln"], cfg.rms_eps)
-                y, _, _ = mamba2_forward(cfg, xp["ssm"], x)
-                return c + y, None
-
-            hh, _ = jax.lax.scan(inner, hh, xs)
-            hh = attn_block(cfg, shared, hh, positions,
-                            jnp.int32(1))          # shared global attention
-            return hh, None
-
-        body = super_block
-        if cfg.remat == "full":
-            body = jax.checkpoint(body)
-        h, _ = jax.lax.scan(body, h, stacked)
-        return h
+        # the embedding h enters with is Zamba2's original hidden state
+        return _hybrid_layers(cfg, params, h, h, positions)[0]
 
     idxs = jnp.arange(cfg.num_layers, dtype=jnp.int32)
 
@@ -305,7 +396,7 @@ def cache_shapes(cfg: ModelConfig, batch_size: int, max_len: int) -> dict:
     """Abstract KV/SSM cache description for one device-visible batch."""
     hd = cfg.resolved_head_dim if cfg.num_heads else 0
     shapes: dict = {"index": ((), "int32", ())}
-    if cfg.family == "ssm":
+    if cfg.family in ("ssm", "hybrid"):
         s = cfg.ssm
         nh, di = s.n_heads(cfg.d_model), s.d_inner(cfg.d_model)
         conv_ch = di + 2 * s.n_groups * s.d_state
@@ -315,24 +406,13 @@ def cache_shapes(cfg: ModelConfig, batch_size: int, max_len: int) -> dict:
         shapes["conv_state"] = (
             (cfg.num_layers, batch_size, s.d_conv - 1, conv_ch),
             cfg.dtype, ("layers", "batch", "conv", "ssm_inner"))
-        return shapes
-    if cfg.family == "hybrid":
-        s = cfg.ssm
-        nh, di = s.n_heads(cfg.d_model), s.d_inner(cfg.d_model)
-        conv_ch = di + 2 * s.n_groups * s.d_state
-        n_super = cfg.num_layers // cfg.hybrid_attn_every
-        shapes["ssm_state"] = (
-            (cfg.num_layers, batch_size, nh, s.head_dim, s.d_state),
-            "float32", ("layers", "batch", "ssm_heads", "qk_dim", "ssm_state"))
-        shapes["conv_state"] = (
-            (cfg.num_layers, batch_size, s.d_conv - 1, conv_ch),
-            cfg.dtype, ("layers", "batch", "conv", "ssm_inner"))
-        shapes["k"] = ((n_super, batch_size, max_len, cfg.num_kv_heads, hd),
-                       cfg.dtype,
-                       ("layers", "batch", "cache_seq", "kv_heads", "qk_dim"))
-        shapes["v"] = ((n_super, batch_size, max_len, cfg.num_kv_heads, hd),
-                       cfg.dtype,
-                       ("layers", "batch", "cache_seq", "kv_heads", "v_dim"))
+        if cfg.family == "hybrid":       # one KV cache per invocation
+            kv = (len(cfg.hybrid_layer_ids), batch_size, max_len,
+                  cfg.num_kv_heads, hd)
+            shapes["k"] = (kv, cfg.dtype, ("layers", "batch", "cache_seq",
+                                           "kv_heads", "qk_dim"))
+            shapes["v"] = (kv, cfg.dtype, ("layers", "batch", "cache_seq",
+                                           "kv_heads", "v_dim"))
         return shapes
     if cfg.mla is not None:
         m = cfg.mla
@@ -385,46 +465,8 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
         new_cache = dict(cache, ssm_state=new_ssm, conv_state=new_conv,
                          index=index + 1)
     elif cfg.family == "hybrid":
-        every = cfg.hybrid_attn_every
-        n_super = cfg.num_layers // every
-        stacked = jax.tree.map(
-            lambda x: x.reshape(n_super, every, *x.shape[1:]),
-            params["layers"])
-        sstates = jax.tree.map(
-            lambda x: x.reshape(n_super, every, *x.shape[1:]),
-            cache["ssm_state"])
-        cstates = jax.tree.map(
-            lambda x: x.reshape(n_super, every, *x.shape[1:]),
-            cache["conv_state"])
-        shared = params["shared_attn"]
-
-        def super_block(carry, xs):
-            hh = carry
-            sp, sst, cst, ck, cv = xs
-
-            def inner(c, xp):
-                lp, s1, c1 = xp
-                x = rms_norm(c, lp["ln"], cfg.rms_eps)
-                y, ns, nc = mamba2_forward(cfg, lp["ssm"], x, ssm_state=s1,
-                                           conv_state=c1, decode=True)
-                return c + y, (ns, nc)
-
-            hh, (ns, nc) = jax.lax.scan(inner, hh, (sp, sst, cst))
-            x = rms_norm(hh, shared["ln1"], cfg.rms_eps)
-            y, nk, nv = gqa_decode(cfg, shared["attn"], x, ck, cv, index)
-            hh = hh + y
-            x = rms_norm(hh, shared["ln2"], cfg.rms_eps)
-            hh = hh + mlp_forward(cfg, shared["mlp"], x)
-            return hh, (ns, nc, nk, nv)
-
-        h, (ns, nc, nk, nv) = jax.lax.scan(
-            super_block, h, (stacked, sstates, cstates,
-                             cache["k"], cache["v"]))
-        new_cache = dict(
-            cache,
-            ssm_state=ns.reshape(cfg.num_layers, *ns.shape[2:]),
-            conv_state=nc.reshape(cfg.num_layers, *nc.shape[2:]),
-            k=nk, v=nv, index=index + 1)
+        h, new = _hybrid_layers(cfg, params, h, h, None, cache=cache)
+        new_cache = dict(cache, **new, index=index + 1)
     elif cfg.mla is not None:
         idxs = jnp.arange(cfg.num_layers, dtype=jnp.int32)
 
@@ -472,15 +514,28 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
     return logits[:, -1], new_cache
 
 
-def prefill(cfg: ModelConfig, params: dict, batch: dict):
+def prefill(cfg: ModelConfig, params: dict, batch: dict, max_len: int = 0):
     """Process a full prompt; returns last-token logits.
 
     (Cache materialization from prefill is family-specific; for workload
     export purposes the compute graph of the forward pass is the prefill
     cost — the cache write adds only bandwidth, modeled in the estimators.)
+    For the hybrid family, ``max_len`` > 0 also returns the cache, of
+    ``max_len`` positions, that :func:`decode_step` continues from.
     """
     h = _embed(cfg, params, batch)
     positions = _positions(batch)
+    if max_len:
+        if cfg.family != "hybrid":
+            raise NotImplementedError(
+                f"prefill builds a cache for the hybrid family only, not "
+                f"{cfg.family!r}")
+        h, cache = _hybrid_layers(cfg, params, h, h, positions, keep=True)
+        s = h.shape[1]
+        pad = ((0, 0), (0, 0), (0, max_len - s), (0, 0), (0, 0))
+        cache = dict(cache, k=jnp.pad(cache["k"], pad),
+                     v=jnp.pad(cache["v"], pad), index=jnp.int32(s))
+        return _logits(cfg, params, h)[:, -1], cache
     h = _scan_layers(cfg, params, h, positions,
                      batch.get("mrope_positions"))
     logits = _logits(cfg, params, h)

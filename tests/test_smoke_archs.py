@@ -109,6 +109,12 @@ def test_full_config_matches_assignment(arch):
                               d_ff=5120, vocab_size=504, causal=False),
         "qwen2-vl-7b": dict(num_layers=28, d_model=3584, num_heads=28,
                             num_kv_heads=4, d_ff=18944, vocab_size=152064),
+        "zamba2-7b": dict(num_layers=81, d_model=3584, num_heads=32,
+                          num_kv_heads=32, head_dim=224, d_ff=14336,
+                          vocab_size=32000, num_mem_blocks=2,
+                          adapter_rank=128,
+                          hybrid_layer_ids=[6, 11, 17, 23, 29, 35, 41, 47,
+                                            53, 59, 65, 71, 77]),
     }[arch]
     for k, v in expected.items():
         assert getattr(cfg, k) == v, f"{arch}.{k}: {getattr(cfg, k)} != {v}"
@@ -124,6 +130,7 @@ def test_param_counts_plausible():
                          ("mixtral-8x22b", 130e9, 150e9),
                          ("deepseek-v3-671b", 600e9, 720e9),
                          ("zamba2-2.7b", 2.2e9, 3.2e9),
+                         ("zamba2-7b", 7.0e9, 7.8e9),
                          ("hubert-xlarge", 0.8e9, 1.4e9),
                          ("qwen2-vl-7b", 6.5e9, 9e9)]:
         total, active = get_config(arch).param_count()
@@ -142,6 +149,17 @@ def test_shape_cell_assignment_rules():
     assert skip_reason(get_config("hubert-xlarge"), "decode_32k")
     assert skip_reason(get_config("deepseek-67b"), "long_500k")
     assert skip_reason(get_config("mamba2-370m"), "train_4k") is None
+
+
+def test_hybrid_param_count_is_the_tree_count():
+    """The analytic count of Zamba2's structure (81 Mamba2 layers, two
+    shared blocks, 13 invocations' linears and adapters, tied embedding)
+    is the parameter tree's, at full size and at the benchmark's cut."""
+    from repro.models.params import param_count
+    for cfg in (get_config("zamba2-7b"), get_config("zamba2-2.7b"),
+                get_config("zamba2-7b").scaled(num_layers=12,
+                                               hybrid_layer_ids=[6, 11])):
+        assert cfg.param_count()[0] == param_count(model_specs(cfg))
 
 
 def test_smoke_param_trees_match_full_structure():
