@@ -107,8 +107,8 @@ def kernels_phase(*, attn=(2, 32, 8, SEQ, 64), rms=(16384, 2048),
     from repro.kernels.flash_attention.ref import attention_ref
     from repro.kernels.rmsnorm.ops import rmsnorm
     from repro.kernels.rmsnorm.ref import rmsnorm_ref
-    from repro.kernels.ssd_scan.ops import ssd_scan
     from repro.kernels.ssd_scan.ref import ssd_ref
+    from repro.models.ssm import ssd_chunked
 
     keys = iter(jax.random.split(jax.random.PRNGKey(SEED), 16))
     bf16 = jnp.bfloat16
@@ -135,7 +135,7 @@ def kernels_phase(*, attn=(2, 32, 8, SEQ, 64), rms=(16384, 2048),
         dt = jax.nn.softplus(normal((b, s, h), jnp.float32) - 2.0)
         a = -jnp.exp(normal((h,), jnp.float32) * 0.5)
         bi, ci = normal((b, s, g, n)), normal((b, s, g, n))
-        y, state = ssd_scan(x, dt, a, bi, ci, chunk=chunk)
+        y, state = ssd_chunked(x, dt, a, bi, ci, chunk, kernel=True)
         y_ref, state_ref = ssd_ref(x, dt, a, bi, ci)
         errs["ssd_scan"] = max(rel_err(y, y_ref), rel_err(state, state_ref))
     for name, err in errs.items():

@@ -1,10 +1,13 @@
 """Mamba2 / SSD (state-space duality) blocks — arXiv:2405.21060.
 
 Training/prefill uses the chunked dual form: intra-chunk attention-like
-matmuls (MXU-friendly — this is the Pallas kernel target) plus an
-inter-chunk state recurrence.  Decode uses the O(1) recurrent update.
+matmuls (on a TPU the Pallas pair of ``kernels/ssd_scan``, see
+:func:`ssd_chunked`) plus an inter-chunk state recurrence.  Decode uses
+the O(1) recurrent update.
 """
 from __future__ import annotations
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -56,10 +59,21 @@ def _segsum(cs: jax.Array) -> jax.Array:
     return jnp.where(mask, out, -jnp.inf)
 
 
+#: SSD calls traced so far, by the path each took ("kernel" or "jnp")
+SSD_PATHS: collections.Counter = collections.Counter()
+
+
+def _kernel_applies(x: jax.Array, b_in: jax.Array, chunk: int) -> bool:
+    """The Pallas pair runs on a TPU, where ``ops.takes_kernel`` holds."""
+    from ..kernels.ssd_scan import ops
+    return jax.default_backend() == "tpu" and ops.takes_kernel(
+        x.shape, b_in.shape[3], b_in.shape[2], chunk, x.dtype)
+
+
 def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
                 c_in: jax.Array, chunk: int,
                 initial_state: jax.Array | None = None,
-                use_pallas: bool = False):
+                kernel: bool | None = None):
     """SSD dual form.
 
     x:  [B, S, H, P]  (P = head dim)
@@ -71,11 +85,21 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
     B and C are contracted once per group: the heads of a group share the
     intra-chunk scores C·Bᵀ, the chunk states' B and the inter-chunk
     output's C. Only the decay mask exp(segsum(dt·a)) is per head.
+
+    The intra-chunk part (scores, decay mask, their product with dt·x and
+    the inbound-state term) runs on the Pallas pair of
+    ``kernels/ssd_scan`` (``ssd_chunk_fwd``/``ssd_chunk_bwd``, its own
+    VJP, the L×L tiles kept in VMEM) where the backend is a TPU and
+    ``ops.takes_kernel`` holds: a chip's heads under the mesh are whole
+    groups or lie in one group, its shapes fit the kernels' tiling, and
+    the group count is one the pair was measured to pay at.  Otherwise,
+    and on the CPU, it runs on the jnp path below, the kernel's reference.
+    ``kernel`` forces either path (the tests'); each trace counts its path
+    in :data:`SSD_PATHS`.  Decode steps take :func:`ssd_decode_step`.
     """
-    if use_pallas:
-        from ..kernels.ssd_scan.ops import ssd_scan
-        return ssd_scan(x, dt, a, b_in, c_in, chunk=chunk,
-                        initial_state=initial_state)
+    if kernel is None:
+        kernel = _kernel_applies(x, b_in, chunk)
+    SSD_PATHS["kernel" if kernel else "jnp"] += 1
     bsz, s, h, p = x.shape
     g, n = b_in.shape[2], b_in.shape[3]
     nc = s // chunk
@@ -93,12 +117,13 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
     da_total = da_cs[:, :, -1]                            # [B,C,G,E]
     xdt = xc * dtc[..., None]                             # dt-weighted input
 
-    # ---- intra-chunk (dual / attention-like) ----
-    # [B,C,G,E,L,L], from the cumsum the chunk states use
-    lmat = jnp.exp(_segsum(da_cs.transpose(0, 1, 3, 4, 2)))
-    cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc)         # [B,C,G,L,S]
-    scores = cb[:, :, :, None] * lmat                     # [B,C,G,E,L,S]
-    y_diag = jnp.einsum("bcgels,bcsgep->bclgep", scores, xdt)
+    if not kernel:
+        # ---- intra-chunk (dual / attention-like) ----
+        # [B,C,G,E,L,L], from the cumsum the chunk states use
+        lmat = jnp.exp(_segsum(da_cs.transpose(0, 1, 3, 4, 2)))
+        cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc)     # [B,C,G,L,S]
+        scores = cb[:, :, :, None] * lmat                 # [B,C,G,E,L,S]
+        y_diag = jnp.einsum("bcgels,bcsgep->bclgep", scores, xdt)
 
     # ---- chunk states ----
     decay_to_end = jnp.exp(da_total[:, :, None] - da_cs)  # [B,C,L,G,E]
@@ -118,6 +143,14 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b_in: jax.Array,
         step, init,
         (states.reshape(bsz, nc, h, p, n).transpose(1, 0, 2, 3, 4),
          da_total.reshape(bsz, nc, h).transpose(1, 0, 2)))
+
+    if kernel:
+        from ..kernels.ssd_scan.ops import ssd_intra
+        group_major = lambda t: t.reshape(bsz, s, g, n).transpose(0, 2, 1, 3)
+        y = ssd_intra(x, dt.astype(f32), group_major(bc), group_major(cc),
+                      da_cs.reshape(bsz, s, h), prev_states)
+        return y, final
+
     prev_states = prev_states.transpose(1, 0, 2, 3, 4).reshape(
         bsz, nc, g, hpg, p, n)                            # [B,C,G,E,P,N]
 
@@ -221,8 +254,7 @@ def mamba2_forward(cfg: ModelConfig, p: dict, hidden: jax.Array,
         else:
             y, new_state = ssd_chunked(
                 x_in, dt, a, b_in, c_in, chunk=min(s_cfg.chunk_size, s),
-                initial_state=ssm_state,
-                use_pallas=cfg.attn_impl == "pallas")
+                initial_state=ssm_state)
         y = y + x_in * p["d_skip"].astype(hidden.dtype)[None, None, :, None]
     y = gated_rms_norm(y.reshape(bsz, s, di), z, p["out_norm"], g,
                        cfg.rms_eps)
