@@ -3,8 +3,8 @@
 One :class:`ModelConfig` describes any architecture in the assigned pool
 (dense, MoE, SSM, hybrid, encoder-only, VLM backbone); one
 :class:`ShapeConfig` describes a workload shape cell (train_4k, prefill_32k,
-decode_32k, long_500k); one :class:`RunConfig` binds them to a mesh and
-training hyperparameters.
+decode_32k, long_500k); one :class:`RunConfig` binds them to training
+hyperparameters (the mesh is ``train(..., mesh=)``'s argument).
 """
 from __future__ import annotations
 
@@ -20,6 +20,9 @@ class MoEConfig:
     d_ff_shared: int = 0
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
+    # router scores: "softmax" over the experts, or "sigmoid" renormalised
+    # over the top k (DeepSeek-V3's config.json key of the same name)
+    scoring_func: str = "softmax"
 
 
 @dataclass(frozen=True)
@@ -97,19 +100,10 @@ class ModelConfig:
     scan_layers: bool = True       # False: python-unrolled layer stack
     layer_barriers: bool = False   # insert optimization_barrier between
     #                                layers (profiling-slicing boundaries)
-    # --- perf knobs (EXPERIMENTS.md §Perf) ---
     loss_vocab_chunk: int = 0      # >0: stream CE over vocab chunks (no
     #                                [B,S,V] f32 logits materialization)
-    moe_dispatch_sharding: bool = False  # sharding constraints on the MoE
-    #                                dispatch path (keeps token-major
-    #                                tensors on the data axis, expert
-    #                                buffers on the model axis)
     moe_ep_shardmap: bool = False  # explicit expert-parallel dispatch via
     #                                shard_map (see mlp.moe_forward_ep)
-    pad_heads: int = 0             # pad Q heads so (H+pad) divides the TP
-    #                                degree; padded head outputs are masked
-    #                                before W_o, so the math is EXACT and
-    #                                pad-row gradients are identically zero
 
     def __post_init__(self):
         # a list, so that it equals the list a JSON configuration states
@@ -211,17 +205,11 @@ SHAPES = {s.name: s for s in (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)}
 class RunConfig:
     model: ModelConfig
     shape: ShapeConfig
-    mesh_shape: tuple[int, ...] = (16, 16)
-    mesh_axes: tuple[str, ...] = ("data", "model")
     learning_rate: float = 3e-4
     warmup_steps: int = 100        # linear learning-rate warmup
     weight_decay: float = 0.1
     optimizer: str = "adamw"       # adamw | adafactor
     grad_clip: float = 1.0
-    param_dtype: str = "bfloat16"
-    opt_state_dtype: str = "float32"
     microbatch: int = 0            # 0 = no gradient accumulation
     gradient_compression: bool = False
     seed: int = 0
-    # long-context decode: shard the KV cache / SSM chunks along "data"
-    sequence_sharded_cache: bool = False

@@ -1,7 +1,7 @@
 """deepseek-v3-671b [moe]: 61L d_model=7168, MLA, 1 shared + 256 routed
 top-8 experts d_ff=2048, vocab=129280 [arXiv:2412.19437].
 
-Deviations noted in DESIGN.md: all layers MoE (the real model's first 3
+Deviations from the published model: all layers MoE (the real model's first 3
 layers are dense); sigmoid top-k routing without the group-limited device
 constraint; MTP head omitted."""
 from .base import MLAConfig, MoEConfig, ModelConfig
@@ -13,7 +13,7 @@ CONFIG = ModelConfig(
                   qk_rope_head_dim=64, v_head_dim=128),
     moe=MoEConfig(num_experts=256, top_k=8, d_ff_expert=2048,
                   num_shared_experts=1, d_ff_shared=2048,
-                  capacity_factor=1.25),
+                  capacity_factor=1.25, scoring_func="sigmoid"),
 )
 
 SMOKE = ModelConfig(
@@ -22,6 +22,7 @@ SMOKE = ModelConfig(
     mla=MLAConfig(q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
                   qk_rope_head_dim=8, v_head_dim=16),
     moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=32,
-                  num_shared_experts=1, d_ff_shared=32),
+                  num_shared_experts=1, d_ff_shared=32,
+                  scoring_func="sigmoid"),
     remat="none",
 )

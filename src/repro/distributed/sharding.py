@@ -2,8 +2,7 @@
 
 Every parameter and activation carries a tuple of *logical* axis names;
 rule tables map logical names to mesh axes.  Swapping rule tables re-shards
-the whole model without touching model code — this is what the perf-model
-pre-flight iterates over when hillclimbing (EXPERIMENTS.md §Perf).
+the whole model without touching model code.
 """
 from __future__ import annotations
 

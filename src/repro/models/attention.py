@@ -39,11 +39,9 @@ def attention_specs(cfg: ModelConfig, stacked: int = 0,
     """GQA projection specs; ``stacked``>0 prepends a layer axis (for scan).
     ``d_in`` is the input width where it is not ``d_model`` (Zamba2's
     shared attention reads 2·d_model and writes d_model)."""
-    d, h, kv, hd = (cfg.d_model, cfg.num_heads + cfg.pad_heads,
-                    cfg.num_kv_heads, cfg.resolved_head_dim)
+    d, h, kv, hd = (cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+                    cfg.resolved_head_dim)
     d_in = d_in or d
-    if cfg.pad_heads:
-        assert h % kv == 0, (h, kv)
     dt = cfg.dtype
 
     def p(shape, axes, **kw):
@@ -245,25 +243,6 @@ def multihead_attention(q, k, v, args: AttnArgs, impl: str = "chunked",
 # GQA layer (projections + rope + attention)
 # --------------------------------------------------------------------------
 
-def _head_mask(cfg: ModelConfig, out: jax.Array) -> jax.Array:
-    """Zero padded-head outputs (out: [..., H+pad, hd]) before W_o.
-
-    GQA maps query head i to kv head i // group_size, so padding must be
-    distributed per group (pad % kv == 0) and the real heads of group g
-    occupy positions [g·group_new, g·group_new + group_old); masking those
-    positions' complement keeps the padding mathematically invisible in
-    both passes (pad-row gradients are identically zero)."""
-    if not cfg.pad_heads:
-        return out
-    kv = cfg.num_kv_heads
-    assert cfg.pad_heads % kv == 0, (cfg.pad_heads, kv)
-    group_new = (cfg.num_heads + cfg.pad_heads) // kv
-    group_old = cfg.num_heads // kv
-    h_total = cfg.num_heads + cfg.pad_heads
-    mask = ((jnp.arange(h_total) % group_new) < group_old).astype(out.dtype)
-    return out * mask[..., :, None]
-
-
 def gqa_forward(cfg: ModelConfig, p: dict, x: jax.Array,
                 positions: jax.Array, *, layer_window: int = 0,
                 mrope_positions: jax.Array | None = None,
@@ -272,8 +251,6 @@ def gqa_forward(cfg: ModelConfig, p: dict, x: jax.Array,
     ``return_kv`` also the keys (after rope) and values, [B, S, kv, hd],
     as a decode cache holds them."""
     b, s, d = x.shape
-    h, kv, hd = (cfg.num_heads + cfg.pad_heads, cfg.num_kv_heads,
-                 cfg.resolved_head_dim)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -295,7 +272,6 @@ def gqa_forward(cfg: ModelConfig, p: dict, x: jax.Array,
         v.transpose(0, 2, 1, 3), args, impl=cfg.attn_impl,
         chunk=cfg.attn_chunk)
     out = out.transpose(0, 2, 1, 3)                      # [B, S, H, hd]
-    out = _head_mask(cfg, out)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return (y, k, v) if return_kv else y
 
@@ -356,7 +332,6 @@ def gqa_decode(cfg: ModelConfig, p: dict, x: jax.Array, cache_k: jax.Array,
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, vt.astype(jnp.float32))
     out = out.reshape(b, hq, 1, -1).transpose(0, 2, 1, 3).astype(x.dtype)
-    out = _head_mask(cfg, out)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
     return y, cache_k, cache_v
 

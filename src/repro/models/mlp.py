@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from ..distributed.sharding import constrain
 from .common import activation, dense
 from .params import ParamSpec
 
@@ -102,6 +101,27 @@ def _capacity(tokens: int, mo) -> int:
     return max(8, -(-c // 8) * 8)   # multiple of 8, >= 8
 
 
+def _route(cfg: ModelConfig, p: dict, xf: jax.Array):
+    """Top-k routing of tokens ``xf`` [T, d]: ``(top_w, top_e)``, each
+    [T, k].  ``scoring_func`` "softmax" takes the experts' softmax;
+    "sigmoid" (DeepSeek-V3) takes sigmoid scores and renormalises the top
+    k to sum to one."""
+    mo = cfg.moe
+    if mo.scoring_func not in ("softmax", "sigmoid"):
+        raise ValueError(f"unknown MoE scoring_func {mo.scoring_func!r}")
+    router_dt = jnp.float32 if mo.router_dtype == "float32" else xf.dtype
+    logits = dense(xf.astype(router_dt), p["router"].astype(router_dt),
+                   accum_f32=False)                       # [T, E]
+    if mo.scoring_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    top_w, top_e = jax.lax.top_k(scores, mo.top_k)         # [T, k]
+    if mo.scoring_func == "sigmoid":
+        top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20)
+    return top_w, top_e
+
+
 def moe_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     """Sort-based top-k MoE. x: [B, S, d] -> [B, S, d].
 
@@ -121,22 +141,7 @@ def moe_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     e = mo.num_experts
     cap = _capacity(t, mo)
     xf = x.reshape(t, d)
-
-    router_dt = jnp.float32 if mo.router_dtype == "float32" else x.dtype
-    logits = dense(xf.astype(router_dt), p["router"].astype(router_dt),
-                   accum_f32=False)                       # [T, E]
-    if cfg.name.startswith("deepseek-v3"):
-        scores = jax.nn.sigmoid(logits)                    # DSv3 sigmoid gate
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(scores, k)                # [T, k]
-    if cfg.name.startswith("deepseek-v3"):
-        top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20)
-
-    def shard(arr, axes):
-        return constrain(arr, axes) if cfg.moe_dispatch_sharding else arr
-
-    xf = shard(xf, ("batch", "embed"))
+    top_w, top_e = _route(cfg, p, xf)                      # [T, k]
 
     # ---- assignment list, sorted by expert ----
     flat_e = top_e.reshape(t * k)                          # expert of slot i
@@ -154,11 +159,9 @@ def moe_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     # ---- scatter tokens into [E*C, d] (zero-FLOP data movement) ----
     gathered = jnp.take(xf, stok, axis=0)                  # [T*k, d]
     gathered = jnp.where(keep[:, None], gathered, 0)
-    gathered = shard(gathered, ("batch", "embed"))
     buf = jnp.zeros((e * cap, d), x.dtype)
     buf = buf.at[slot].add(gathered)                       # scatter-add
     buf = buf.reshape(e, cap, d)
-    buf = shard(buf, ("experts", "seq", "embed"))
 
     # ---- grouped expert GEMMs (expert axis sharded = EP) ----
     act = activation(cfg.act)
@@ -168,14 +171,12 @@ def moe_forward(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
                    preferred_element_type=jnp.float32).astype(x.dtype)
     y = jnp.einsum("ecf,efd->ecd", act(g) * u, p["we_down"],
                    preferred_element_type=jnp.float32).astype(x.dtype)
-    y = shard(y, ("experts", "seq", "embed")).reshape(e * cap, d)
+    y = y.reshape(e * cap, d)
 
     # ---- gather back, weighted combine ----
     out_tokens = jnp.take(y, slot, axis=0)                 # [T*k, d]
     out_tokens = out_tokens * (sw * keep)[:, None].astype(x.dtype)
-    out_tokens = shard(out_tokens, ("batch", "embed"))
     out = jnp.zeros((t, d), x.dtype).at[stok].add(out_tokens)
-    out = shard(out, ("batch", "embed"))
 
     if mo.num_shared_experts:
         out = out + mlp_forward(cfg, p, xf, suffix="_shared")
@@ -234,16 +235,7 @@ def moe_forward_ep(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
         return moe_forward(cfg, p, x)
 
     xf = x.reshape(t, d)
-    router_dt = jnp.float32 if mo.router_dtype == "float32" else x.dtype
-    logits = dense(xf.astype(router_dt), p["router"].astype(router_dt),
-                   accum_f32=False)
-    if cfg.name.startswith("deepseek-v3"):
-        scores = jax.nn.sigmoid(logits)
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(scores, k)
-    if cfg.name.startswith("deepseek-v3"):
-        top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20)
+    top_w, top_e = _route(cfg, p, xf)
     top_w = top_w.astype(x.dtype)
 
     t_local = t // max(n_data, 1)
@@ -291,11 +283,7 @@ def moe_forward_ep(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
         return jax.lax.psum(partial, "model")
 
     if scheme == "expert":
-        wspecs = (P("model", None, None), P("model", None, None),
-                  P("model", "mlp_pad", None)
-                  if False else P("model", None, None))
-        wd_spec = P("model", None, None)
-        wg_spec = wu_spec = P("model", None, None)
+        wg_spec = wu_spec = wd_spec = P("model", None, None)
     else:  # ffn: shard d_ff over the model axis
         wg_spec = wu_spec = P(None, None, "model")
         wd_spec = P(None, "model", None)
@@ -313,15 +301,11 @@ def moe_forward_ep(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
     return out.reshape(b, s, d)
 
 
-def moe_aux_loss(cfg: ModelConfig, p: dict, x: jax.Array) -> jax.Array:
-    """Load-balancing auxiliary loss (Switch-style)."""
-    mo = cfg.moe
-    b, s, d = x.shape
-    xf = x.reshape(b * s, d).astype(jnp.float32)
-    logits = dense(xf, p["router"].astype(jnp.float32), accum_f32=False)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top1 = jnp.argmax(probs, axis=-1)
-    frac_tokens = jnp.mean(
-        jax.nn.one_hot(top1, mo.num_experts, dtype=jnp.float32), axis=0)
-    frac_probs = jnp.mean(probs, axis=0)
-    return mo.num_experts * jnp.sum(frac_tokens * frac_probs)
+def ffn(cfg: ModelConfig, lp: dict, x: jax.Array) -> jax.Array:
+    """A layer's feed-forward part on ``x`` [B, S, d]: the MoE layer
+    ``lp["moe"]`` (through :func:`moe_forward_ep` where
+    ``cfg.moe_ep_shardmap``) or the dense MLP ``lp["mlp"]``."""
+    if cfg.moe is None:
+        return mlp_forward(cfg, lp["mlp"], x)
+    fwd = moe_forward_ep if cfg.moe_ep_shardmap else moe_forward
+    return fwd(cfg, lp["moe"], x)

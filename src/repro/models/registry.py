@@ -54,7 +54,7 @@ def skip_reason(cfg: ModelConfig, shape_name: str) -> str | None:
     if cfg.is_encoder_only and shape_name in ("decode_32k", "long_500k"):
         return "encoder-only: no autoregressive decode step"
     if shape_name == "long_500k":
-        return "full-quadratic attention at 524288 tokens (see DESIGN.md)"
+        return "full-quadratic attention at 524288 tokens"
     return "not applicable"
 
 

@@ -30,8 +30,7 @@ from .attention import (attention_specs, gqa_decode, gqa_forward, mla_decode,
                         mla_forward, mla_specs)
 from .common import (dense, embedding_spec, norm_spec, rms_norm,
                      shard_act, softcap)
-from .mlp import (adapter_specs, mlp_forward, mlp_specs, moe_aux_loss,
-                  moe_forward, moe_forward_ep, moe_specs)
+from .mlp import adapter_specs, ffn, mlp_forward, mlp_specs, moe_specs
 from .params import ParamSpec
 from .ssm import mamba2_forward, ssm_specs
 
@@ -122,11 +121,7 @@ def attn_block(cfg: ModelConfig, lp: dict, h: jax.Array,
                         mrope_positions=mrope_positions)
     h = h + shard_act(y, ("batch", "seq", "embed"))
     x = rms_norm(h, lp["ln2"], cfg.rms_eps)
-    if cfg.moe is not None:
-        fwd = moe_forward_ep if cfg.moe_ep_shardmap else moe_forward
-        y = fwd(cfg, lp["moe"], x)
-    else:
-        y = mlp_forward(cfg, lp["mlp"], x)
+    y = ffn(cfg, lp, x)
     return h + shard_act(y, ("batch", "seq", "embed"))
 
 
@@ -329,10 +324,6 @@ def forward(cfg: ModelConfig, params: dict, batch: dict):
             return loss, None
         logits = _logits(cfg, params, h)
         loss = cross_entropy(logits, batch["targets"])
-    if cfg.moe is not None:
-        # router aux loss on the mean hidden state (cheap proxy; per-layer
-        # aux would need scan ys — tracked as beyond-paper TODO)
-        loss = loss + 0.0
     return loss, logits
 
 
@@ -477,11 +468,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
             y, new_ckv = mla_decode(cfg, lp["attn"], x, ckv, index)
             hh = hh + y
             x = rms_norm(hh, lp["ln2"], cfg.rms_eps)
-            if cfg.moe is not None:
-                fwd = moe_forward_ep if cfg.moe_ep_shardmap else moe_forward
-                hh = hh + fwd(cfg, lp["moe"], x)
-            else:
-                hh = hh + mlp_forward(cfg, lp["mlp"], x)
+            hh = hh + ffn(cfg, lp, x)
             return hh, new_ckv
 
         h, new_ckv = jax.lax.scan(
@@ -499,11 +486,7 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, batch: dict):
                                    layer_window=window)
             hh = hh + y
             x = rms_norm(hh, lp["ln2"], cfg.rms_eps)
-            if cfg.moe is not None:
-                fwd = moe_forward_ep if cfg.moe_ep_shardmap else moe_forward
-                hh = hh + fwd(cfg, lp["moe"], x)
-            else:
-                hh = hh + mlp_forward(cfg, lp["mlp"], x)
+            hh = hh + ffn(cfg, lp, x)
             return hh, (nk, nv)
 
         h, (nk, nv) = jax.lax.scan(

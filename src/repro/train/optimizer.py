@@ -2,8 +2,7 @@
 
 Optimizer state is fully sharded: each moment inherits its parameter's
 sharding (which is itself FSDP-sharded over the "data" axis), so per-device
-optimizer bytes scale as 1/|mesh| — required for the 671B MoE config to fit
-a v5e pod (see EXPERIMENTS.md §Dry-run memory table)."""
+optimizer bytes scale as 1/|mesh|."""
 from __future__ import annotations
 
 from dataclasses import dataclass

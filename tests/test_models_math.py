@@ -133,6 +133,38 @@ class TestMoE:
         # doubling experts must NOT double compute (one-hot dispatch would)
         assert f8 < 1.5 * f4
 
+    @pytest.mark.parametrize("arch,scoring", [
+        ("mixtral-8x22b", "softmax"), ("deepseek-v3-671b", "sigmoid")])
+    def test_routing_weights(self, arch, scoring):
+        """Each configuration's scoring rule: softmax over the experts, or
+        sigmoid renormalised over the top k; another rule raises."""
+        from dataclasses import replace
+        from repro.models import get_smoke_config
+        from repro.models.mlp import _route, moe_specs
+        from repro.models.params import init_params
+        cfg = get_smoke_config(arch)
+        assert cfg.moe.scoring_func == scoring
+        p = init_params(moe_specs(cfg), jax.random.PRNGKey(0))
+        xf = jax.random.normal(jax.random.PRNGKey(1), (16, cfg.d_model),
+                               jnp.bfloat16)
+        top_w, top_e = _route(cfg, p, xf)
+        logits = (np.asarray(xf, np.float64)
+                  @ np.asarray(p["router"], np.float64))
+        if scoring == "softmax":
+            z = np.exp(logits - logits.max(-1, keepdims=True))
+            scores = z / z.sum(-1, keepdims=True)
+        else:
+            scores = 1 / (1 + np.exp(-logits))
+        want_e = np.argsort(-scores, axis=-1)[:, :cfg.moe.top_k]
+        want_w = np.take_along_axis(scores, want_e, axis=-1)
+        if scoring == "sigmoid":
+            want_w = want_w / want_w.sum(-1, keepdims=True)
+        np.testing.assert_array_equal(np.asarray(top_e), want_e)
+        np.testing.assert_allclose(np.asarray(top_w), want_w, rtol=1e-4)
+        bad = cfg.scaled(moe=replace(cfg.moe, scoring_func="relu"))
+        with pytest.raises(ValueError, match="scoring_func"):
+            _route(bad, p, xf)
+
 
 class TestSSM:
     def test_causal_conv_is_causal(self):

@@ -11,50 +11,6 @@ from repro.models import forward, get_smoke_config, model_specs
 from repro.models.params import init_params
 
 
-class TestPadHeads:
-    """pad_heads: per-group padded Q heads masked before W_o (EXACT)."""
-
-    def _embed_padded(self, p0, p1, kv, g_old, g_new):
-        def head_map(i):
-            return (i // g_old) * g_new + (i % g_old)
-
-        def embed(a, b):
-            if a.shape == b.shape:
-                return a
-            out = b
-            ax = [i for i, (x, y) in enumerate(zip(a.shape, b.shape))
-                  if x != y][0]
-            for i in range(a.shape[ax]):
-                src = tuple(slice(None) if d != ax else i
-                            for d in range(a.ndim))
-                dst = tuple(slice(None) if d != ax else head_map(i)
-                            for d in range(a.ndim))
-                out = out.at[dst].set(a[src])
-            return out
-        return jax.tree.map(embed, p0, p1)
-
-    def test_exactness_and_zero_pad_grads(self):
-        cfg0 = get_smoke_config("qwen2.5-32b")      # 4 heads, kv=2
-        cfg1 = cfg0.scaled(pad_heads=2)             # group 2 -> 3
-        p0 = init_params(model_specs(cfg0), jax.random.PRNGKey(0))
-        p1 = init_params(model_specs(cfg1), jax.random.PRNGKey(1))
-        p1 = self._embed_padded(p0, p1, kv=2, g_old=2, g_new=3)
-        batch = {
-            "tokens": jax.random.randint(jax.random.PRNGKey(2), (2, 16), 0,
-                                         cfg0.vocab_size),
-            "targets": jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0,
-                                          cfg0.vocab_size),
-        }
-        l0, g0 = forward(cfg0, p0, batch)
-        l1, g1 = forward(cfg1, p1, batch)
-        assert float(abs(l0 - l1)) < 1e-6
-        np.testing.assert_allclose(np.asarray(g0, np.float32),
-                                   np.asarray(g1, np.float32), atol=2e-2)
-        grads = jax.grad(lambda p, b: forward(cfg1, p, b)[0])(p1, batch)
-        wq_g = grads["layers"]["attn"]["wq"]
-        assert float(jnp.abs(wq_g[:, :, [2, 5]]).sum()) == 0.0
-
-
 class TestChunkedLoss:
     def test_matches_full_loss(self):
         cfg0 = get_smoke_config("stablelm-12b")
