@@ -5,8 +5,8 @@ Everything runs in this one process, which starts no other; a chip
 belongs to one process at a time.  Phases, in order:
 
 1. kernels      flash attention and rmsnorm at llama3-1b widths, ssd_scan
-                at mamba2-370m widths, compiled for the chip, each against
-                its ``ref.py`` oracle;
+                and the causal conv at mamba2-370m widths, compiled for
+                the chip, each against its ``ref.py`` oracle;
 2. ground truth ``repro.train.train`` on llama3-1b at its published widths
                 (16 layers, d_model 2048), seq 2048, batch 1, full
                 activation checkpointing, AdamW, random weights from a
@@ -96,13 +96,18 @@ def rel_err(got, want) -> float:
 # ------------------------------------------------------------- phase 1
 
 def kernels_phase(*, attn=(2, 32, 8, SEQ, 64), rms=(16384, 2048),
-                  ssd=(1, SEQ, 32, 64, 1, 128, 256), tol: float = 2e-2):
+                  ssd=(1, SEQ, 32, 64, 1, 128, 256),
+                  conv=(1, SEQ, 4384, 2048, 2304), tol: float = 2e-2):
     """Each Pallas kernel against its oracle; ``attn`` is (batch, query
     heads, kv heads, seq, head dim), ``rms`` (rows, d_model), ``ssd``
-    (batch, seq, heads, head dim, groups, state, chunk)."""
+    (batch, seq, heads, head dim, groups, state, chunk), ``conv`` (batch,
+    seq, width of ``in_proj``'s output, the conv's offset and channels
+    in it)."""
     import jax
     import jax.numpy as jnp
 
+    from repro.kernels.causal_conv.ops import causal_conv_silu
+    from repro.kernels.causal_conv.ref import causal_conv_ref
     from repro.kernels.flash_attention.ops import flash_attention
     from repro.kernels.flash_attention.ref import attention_ref
     from repro.kernels.rmsnorm.ops import rmsnorm
@@ -138,6 +143,12 @@ def kernels_phase(*, attn=(2, 32, 8, SEQ, 64), rms=(16384, 2048),
         y, state = ssd_chunked(x, dt, a, bi, ci, chunk, kernel=True)
         y_ref, state_ref = ssd_ref(x, dt, a, bi, ci)
         errs["ssd_scan"] = max(rel_err(y, y_ref), rel_err(state, state_ref))
+
+        b, s, width, off, c = conv
+        xs, w, bias = normal((b, s, width)), normal((4, c)), normal((c,))
+        errs["causal_conv"] = rel_err(
+            causal_conv_silu(xs, w, bias, off),
+            causal_conv_ref(xs[..., off:off + c], w, bias))
     for name, err in errs.items():
         print(f"kernel {name}: max error {err:.3e} of the oracle's range "
               f"(limit {tol})")

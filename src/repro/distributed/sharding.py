@@ -78,6 +78,13 @@ def _axis_size(mesh: Mesh, name: str) -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape))[name]
 
 
+def model_axis_size(mesh) -> int:
+    """Chips along ``mesh``'s ``model`` axis: 1 without a mesh or axis."""
+    if mesh is None:
+        return 1
+    return dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
+
+
 def _resolve(axes: tuple[str, ...], rules: dict, mesh: Mesh,
              shape: tuple[int, ...] | None = None) -> P:
     """Map logical axes to mesh axes, dropping any mapping whose mesh-axis

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ...distributed.sharding import (ACT_RULES, get_abstract_mesh_or_none,
-                                     logical_to_spec)
+                                     logical_to_spec, model_axis_size)
 from . import kernel
 
 #: group counts G at which the pair was measured to pay in a training step
@@ -44,19 +44,13 @@ def _should_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _model_axis(mesh) -> int:
-    if mesh is None:
-        return 1
-    return dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
-
-
 def chip_heads(mesh, heads: int, groups: int):
     """How the heads split over ``mesh``'s ``model`` axis, M: (heads a
     chip, groups a chip, copies of each group's B and C).  A chip holds
     whole groups where M divides G, else the heads of one group where G
     divides M and M / G divides the group's heads (B and C then in M / G
     copies, one a chip); None where neither holds."""
-    m = _model_axis(mesh)
+    m = model_axis_size(mesh)
     if groups % m == 0:
         return heads // m, groups // m, 1
     if m % groups == 0 and (heads // groups) % (m // groups) == 0:
@@ -147,7 +141,7 @@ def ssd_intra(x, dt, b, c, cs, states):
     if mesh is not None:
         rows = logical_to_spec(("batch",), ACT_RULES, mesh, (bsz,))
         rows = rows[0] if len(rows) else None
-        heads = "model" if _model_axis(mesh) > 1 else None
+        heads = "model" if model_axis_size(mesh) > 1 else None
         act = P(rows, None, heads)
         grp = P(rows, heads)
         op = jax.shard_map(op, mesh=mesh,

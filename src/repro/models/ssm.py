@@ -182,7 +182,8 @@ def ssd_decode_step(x, dt, a, b_in, c_in, state):
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """Depthwise causal conv. x: [B, S, C]; w: [K, C]."""
+    """Depthwise causal conv. x: [B, S, C]; w: [K, C].  With its SiLU, the
+    reference of the Pallas pair of ``kernels/causal_conv``."""
     k = w.shape[0]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     out = jnp.zeros_like(x, dtype=jnp.float32)
@@ -190,6 +191,29 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
         out = out + xp[:, i:i + x.shape[1]].astype(jnp.float32) * \
             w[i].astype(jnp.float32)
     return (out + b.astype(jnp.float32)).astype(x.dtype)
+
+
+#: causal convs traced so far, by the path each took ("kernel" or "jnp")
+CONV_PATHS: collections.Counter = collections.Counter()
+
+
+def _conv_kernel_applies(zxbcdt: jax.Array, offset: int, w: jax.Array,
+                         ssd_kernel: bool) -> bool:
+    """The conv's Pallas pair runs on a TPU where the SSD's pair runs too
+    (``ssd_kernel``) and ``ops.takes_kernel`` holds for the taps ``w`` [K,
+    C] over the channels ``[offset, offset + C)`` of ``zxbcdt``.
+
+    The pair reads and writes [B, S, C] with the channels minor, as the
+    SSD's pair reads x, B and C.  Where the SSD takes the jnp path, XLA
+    lays ``in_proj``'s output and its gradient out with the sequence minor
+    instead, and the relayouts the pair then forces outweigh it:
+    zamba2-7b's step (G = 2, the SSD's jnp path; TPU v5e x4) was 1.3%
+    slower on the pair, mamba2-370m's (G = 1, the SSD's pair; one TPU v5e)
+    12% faster."""
+    from ..kernels.causal_conv import ops
+    return (ssd_kernel and jax.default_backend() == "tpu"
+            and ops.takes_kernel(zxbcdt.shape, offset, w.shape,
+                                 zxbcdt.dtype))
 
 
 def gated_rms_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int,
@@ -224,6 +248,11 @@ def mamba2_forward(cfg: ModelConfig, p: dict, hidden: jax.Array,
     with jax.named_scope("proj"):
         zxbcdt = dense(hidden, p["in_proj"])
     z, xbc, dt_raw = jnp.split(zxbcdt, [di, di + di + 2 * g * n], axis=-1)
+    # the SSD's path, which the conv's follows (_conv_kernel_applies)
+    chunk = min(s_cfg.chunk_size, s)
+    ssd_kernel = not decode and _kernel_applies(
+        jax.ShapeDtypeStruct((bsz, s, nh, s_cfg.head_dim), zxbcdt.dtype),
+        jax.ShapeDtypeStruct((bsz, s, g, n), zxbcdt.dtype), chunk)
     with jax.named_scope("conv"):
         if decode:
             # rolling conv state: [B, K-1, conv_ch]
@@ -235,8 +264,18 @@ def mamba2_forward(cfg: ModelConfig, p: dict, hidden: jax.Array,
                 + p["conv_b"].astype(jnp.float32)
             xbc_conv = jax.nn.silu(xbc_conv)[:, None].astype(hidden.dtype)
         else:
-            xbc_conv = jax.nn.silu(
-                _causal_conv(xbc, p["conv_w"], p["conv_b"]))
+            # on a TPU the Pallas pair reads xbc's channels in place from
+            # in_proj's output; elsewhere the jnp path, its reference
+            kernel = _conv_kernel_applies(zxbcdt, di, p["conv_w"],
+                                          ssd_kernel)
+            CONV_PATHS["kernel" if kernel else "jnp"] += 1
+            if kernel:
+                from ..kernels.causal_conv.ops import causal_conv_silu
+                xbc_conv = causal_conv_silu(zxbcdt, p["conv_w"],
+                                            p["conv_b"], di)
+            else:
+                xbc_conv = jax.nn.silu(
+                    _causal_conv(xbc, p["conv_w"], p["conv_b"]))
             new_conv_state = xbc[:, -(p["conv_w"].shape[0] - 1):]
 
     x_in, b_in, c_in = jnp.split(xbc_conv, [di, di + g * n], axis=-1)
@@ -253,8 +292,8 @@ def mamba2_forward(cfg: ModelConfig, p: dict, hidden: jax.Array,
                                            ssm_state)
         else:
             y, new_state = ssd_chunked(
-                x_in, dt, a, b_in, c_in, chunk=min(s_cfg.chunk_size, s),
-                initial_state=ssm_state)
+                x_in, dt, a, b_in, c_in, chunk=chunk,
+                initial_state=ssm_state, kernel=ssd_kernel)
         y = y + x_in * p["d_skip"].astype(hidden.dtype)[None, None, :, None]
     y = gated_rms_norm(y.reshape(bsz, s, di), z, p["out_norm"], g,
                        cfg.rms_eps)

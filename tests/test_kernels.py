@@ -10,6 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.causal_conv import kernel as conv_kernel
+from repro.kernels.causal_conv import ops as conv_ops
+from repro.kernels.causal_conv.ref import causal_conv_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.rmsnorm.ops import rmsnorm
@@ -300,6 +303,217 @@ class TestSSDPathSelection:
         monkeypatch.setattr(ops, "MEASURED_GROUPS", (1, 2, 3, 4))
         assert ops.takes_kernel((1, 512, h, 64), 128, g, 128,
                                 jnp.bfloat16) is takes
+
+
+def _conv_inputs(b, s, total, width, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    xs = jax.random.normal(ks[0], (b, s, total), jnp.float32).astype(dtype)
+    w = (jax.random.normal(ks[1], (4, width)) * 0.5).astype(dtype)
+    bias = (jax.random.normal(ks[2], (width,)) * 0.5).astype(dtype)
+    wy = jax.random.normal(ks[3], (b, s, width))
+    return xs, w, bias, wy
+
+
+def _conv_value_and_grads(kernel, xs, w, bias, wy, offset):
+    """silu(causal conv) of xs's channels [offset, offset + C) and the
+    gradients of a loss of it w.r.t. xs, w and b, on the kernel pair or on
+    the jnp path (``_causal_conv`` and its SiLU)."""
+    def conv(xs, w, bias):
+        if kernel:
+            return conv_ops.causal_conv_silu(xs, w, bias, offset)
+        xbc = xs[..., offset:offset + w.shape[1]]
+        return jax.nn.silu(ssm._causal_conv(xbc, w, bias))
+
+    def loss(xs, w, bias):
+        y = conv(xs, w, bias)
+        return jnp.sum(y.astype(jnp.float32) * wy), y
+
+    return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))(
+        xs, w, bias)
+
+
+class TestCausalConvKernelVJP:
+    """The conv pair (interpreted on the CPU) against the jnp path:
+    forward and the gradients of xs (zero outside the conv's channels), w
+    and b.  With f32 activations they differ by summation order; with
+    bf16 ones by where the SiLU and its derivative round (the jnp path
+    rounds each to bf16, the kernel keeps f32 until its outputs), a few
+    units of bf16's last place (2**-8) of the largest value."""
+
+    @pytest.mark.parametrize("b,s,total,offset,width,dtype,tol", [
+        (2, 128, 1024, 256, 512, jnp.float32, 1e-5),   # two row chunks
+        (1, 64, 256, 0, 256, jnp.float32, 1e-5),       # every channel
+        (2, 192, 768, 512, 256, jnp.bfloat16, 2e-2),   # the last channels
+        (1, 128, 4384, 2048, 2304, jnp.bfloat16, 2e-2),    # mamba2-370m
+        (1, 64, 14704, 7168, 7424, jnp.bfloat16, 2e-2),    # zamba2-7b
+    ])
+    def test_matches_jnp_path(self, b, s, total, offset, width, dtype, tol):
+        args = _conv_inputs(b, s, total, width, dtype)
+        (_, yk), gk = _conv_value_and_grads(True, *args, offset)
+        (_, yj), gj = _conv_value_and_grads(False, *args, offset)
+
+        def close(got, want):
+            got, want = (np.asarray(t, np.float32) for t in (got, want))
+            assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+
+        close(yk, yj)
+        # the first K - 1 rows, where the taps read the zeros before t = 0
+        close(yk[:, :3], yj[:, :3])
+        for got, want in zip(gk, gj):
+            close(got, want)
+        dxs = np.asarray(gk[0], np.float32)
+        assert not dxs[..., :offset].any()
+        assert not dxs[..., offset + width:].any()
+
+    def test_taps_reach_across_row_chunks(self, monkeypatch):
+        """Chunks of 8 rows: each chunk's taps read the previous chunk's
+        last rows, and dx's the next chunk's first; the result is the
+        one-chunk result, up to the order of dw's and db's sums."""
+        args = _conv_inputs(1, 64, 512, 256, jnp.float32)
+        (_, y1), g1 = _conv_value_and_grads(True, *args, 256)
+        monkeypatch.setattr(conv_kernel, "ROWS", 8)
+        (_, y2), g2 = _conv_value_and_grads(True, *args, 256)
+        for got, want in zip((y2, *g2), (y1, *g1)):
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 *
+                                       float(jnp.max(jnp.abs(want))))
+
+    def test_jnp_path_matches_the_reference(self):
+        """The jnp path, the kernel's reference, against ``ref.py``'s
+        grouped convolution."""
+        xs, w, bias, _ = _conv_inputs(2, 64, 256, 256, jnp.float32)
+        got = jax.nn.silu(ssm._causal_conv(xs, w, bias))
+        np.testing.assert_allclose(got, causal_conv_ref(xs, w, bias),
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_matches_jnp_path_on_a_mesh(self, tmp_path):
+        """Under a (4, 1) mesh of 4 CPU devices (a subprocess: the device
+        count is fixed at start-up) the pair runs in ``shard_map`` on each
+        chip's rows; loss and gradients match the jnp path's."""
+        script = tmp_path / "conv_mesh_check.py"
+        script.write_text(_CONV_MESH_CHECK % os.path.join(
+            os.path.dirname(__file__), "..", "src"))
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop("XLA_FLAGS", None)
+        proc = subprocess.run([sys.executable, str(script)], env=env,
+                              capture_output=True, text=True, timeout=600)
+        assert proc.returncode == 0, proc.stdout + proc.stderr
+        assert proc.stdout.strip().endswith("OK"), proc.stdout
+
+
+_CONV_MESH_CHECK = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys; sys.path.insert(0, %r)
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.kernels.causal_conv import ops
+from repro.models import ssm
+
+ks = jax.random.split(jax.random.PRNGKey(0), 4)
+b, s, total, offset, width = 8, 64, 1024, 256, 512
+args = (jax.random.normal(ks[0], (b, s, total)),
+        jax.random.normal(ks[1], (4, width)) * 0.5,
+        jax.random.normal(ks[2], (width,)) * 0.5)
+wy = jax.random.normal(ks[3], (b, s, width))
+specs = (P("data"), P(), P())
+mesh = Mesh(np.array(jax.devices()).reshape(4, 1), ("data", "model"))
+
+def run(kernel):
+    def loss(xs, w, bias):
+        if kernel:
+            y = ops.causal_conv_silu(xs, w, bias, offset)
+        else:
+            y = jax.nn.silu(ssm._causal_conv(
+                xs[..., offset:offset + width], w, bias))
+        return jnp.sum(y * wy)
+    with mesh:
+        sharded = [jax.device_put(v, NamedSharding(mesh, sp))
+                   for v, sp in zip(args, specs)]
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(*sharded)
+
+(lk, gk), (lj, gj) = run(True), run(False)
+assert abs(float(lk) - float(lj)) <= 1e-5 * abs(float(lj)), (lk, lj)
+for name, got, want in zip(("xs", "w", "b"), gk, gj):
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    assert err <= 1e-5, (name, err)
+print("OK")
+"""
+
+
+class TestCausalConvPathSelection:
+    """``mamba2_forward`` counts the conv's path in ``ssm.CONV_PATHS``:
+    the kernel pair on a TPU where the SSD takes its pair too, the conv's
+    channels and their offset in ``in_proj``'s output are whole channel
+    tiles, the rows whole chunks and no ``model`` axis splits the
+    channels; the jnp path otherwise, and on the CPU.  d_model 128 and
+    heads of 64 in chunks of 128: the SSD's pair tiles at G = 1."""
+
+    @staticmethod
+    def _trace(d_state, seq=128, decode=False, d_conv=4, groups=1):
+        from dataclasses import replace
+
+        from repro.models import get_smoke_config
+        cfg = get_smoke_config("mamba2-370m")
+        cfg = cfg.scaled(d_model=128, ssm=replace(
+            cfg.ssm, d_state=d_state, d_conv=d_conv, n_groups=groups,
+            head_dim=64, chunk_size=128))
+        specs = ssm.ssm_specs(cfg)
+        params = {k: jax.ShapeDtypeStruct(v.shape, jnp.dtype(v.dtype))
+                  for k, v in specs.items()}
+        rows = 1 if decode else seq
+        hidden = jax.ShapeDtypeStruct((2, rows, cfg.d_model), jnp.bfloat16)
+        states = {}
+        if decode:
+            s = cfg.ssm
+            di = s.d_inner(cfg.d_model)
+            states = dict(
+                ssm_state=jax.ShapeDtypeStruct(
+                    (2, s.n_heads(cfg.d_model), s.head_dim, d_state),
+                    jnp.float32),
+                conv_state=jax.ShapeDtypeStruct(
+                    (2, s.d_conv - 1, di + 2 * groups * d_state),
+                    jnp.bfloat16))
+        before = dict(ssm.CONV_PATHS)
+        jax.eval_shape(lambda p, h, st: ssm.mamba2_forward(
+            cfg, p, h, decode=decode, **st), params, hidden, states)
+        return {k: v - before.get(k, 0) for k, v in ssm.CONV_PATHS.items()
+                if v != before.get(k, 0)}
+
+    def test_cpu_takes_the_jnp_path(self):
+        # d_inner 256 and 2·N = 256: the channels tile
+        assert self._trace(128) == {"jnp": 1}
+
+    @pytest.mark.parametrize("d_state,seq,d_conv,groups,path", [
+        (128, 128, 4, 1, "kernel"),    # channels [256, 768) of 772
+        (64, 128, 4, 1, "jnp"),        # 384 channels: one and a half tiles
+        (128, 96, 4, 1, "jnp"),        # 96 rows: one and a half chunks
+        (128, 128, 9, 1, "kernel"),    # taps reach back 8 rows, the halo
+        (128, 128, 10, 1, "jnp"),      # 9 rows: past the halo
+        (64, 128, 4, 2, "jnp"),        # 512 channels tile; the SSD at G = 2
+                                       # takes the jnp path, and so the conv
+    ])
+    def test_tpu_takes_the_kernel_where_it_tiles(self, monkeypatch, d_state,
+                                                 seq, d_conv, groups, path):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert self._trace(d_state, seq, d_conv=d_conv,
+                           groups=groups) == {path: 1}
+
+    def test_decode_keeps_the_rolling_state(self, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert self._trace(128, decode=True) == {}
+
+    @pytest.mark.parametrize("mesh_shape,takes", [
+        ((4, 1), True),      # rows over data, every channel a chip
+        ((2, 2), False),     # a model axis splits the channels
+    ])
+    def test_mesh_keeps_the_channels_whole(self, monkeypatch, mesh_shape,
+                                           takes):
+        mesh = types.SimpleNamespace(axis_names=("data", "model"),
+                                     devices=np.empty(mesh_shape))
+        monkeypatch.setattr(conv_ops, "get_abstract_mesh_or_none",
+                            lambda: mesh)
+        assert conv_ops.takes_kernel((8, 4096, 14704), 7168, (4, 7424),
+                                     jnp.bfloat16) is takes
 
 
 class TestRMSNorm:

@@ -4,13 +4,15 @@ by name (``chipbench/scopes.py``): each must reach the compiled program's
 ``op_name`` metadata, in the forward and the backward pass, so that a
 rename fails here instead of silencing a metric."""
 import re
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from chipbench import scopes
-from repro.models import get_smoke_config
+from repro.kernels.causal_conv import ops as conv_ops
+from repro.models import get_smoke_config, ssm
 from repro.models.params import is_spec
 from repro.models.transformer import model_specs
 from repro.train.loop import make_train_step
@@ -93,3 +95,33 @@ def test_shared_block_is_named_forward_and_backward(hybrid_op_names, name):
 def test_hybrid_step_keeps_the_mamba_layers_names(hybrid_op_names):
     for name in ("proj", "conv", "ssd", "norm", "vocab"):
         assert [p for p in hybrid_op_names if scopes.layer(p) == name], name
+
+
+def test_conv_kernel_pair_is_named_conv(monkeypatch):
+    """On a TPU the conv runs on its Pallas pair (``kernels/causal_conv``):
+    the forward, its remat and the backward kernels fall under ``conv``.
+    The step is lowered for a TPU, not compiled, at widths the pair tiles
+    (channels [256, 768) of ``in_proj``'s 776)."""
+    monkeypatch.setattr(conv_ops, "_should_interpret", lambda: False)
+    monkeypatch.setattr(ssm, "_conv_kernel_applies", lambda z, o, w, ssd:
+                        conv_ops.takes_kernel(z.shape, o, w.shape, z.dtype))
+    cfg = get_smoke_config("mamba2-370m")
+    cfg = cfg.scaled(remat="full", d_model=128,
+                     ssm=replace(cfg.ssm, d_state=128))
+    specs = model_specs(cfg)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.dtype(s.dtype)), specs,
+        is_leaf=is_spec)
+    rows = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    step = make_train_step(cfg, OptimizerConfig(warmup_steps=1))
+    text = jax.jit(step).trace(
+        params, opt_state_abstract(specs, "adamw"),
+        {"tokens": rows, "targets": rows}).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    kernels = set(re.findall(r'loc\("([^"]*/causal_conv_(?:fwd|bwd)/[^"]*)"',
+                             text))
+    fwd = [p for p in kernels if "causal_conv_fwd" in p]
+    assert [p for p in kernels if "causal_conv_bwd" in p], kernels
+    assert [p for p in fwd if "rematted_computation" in p], kernels
+    assert [p for p in fwd if "rematted_computation" not in p], kernels
+    assert {scopes.layer(p) for p in kernels} == {"conv"}, kernels

@@ -18,6 +18,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
     SingleDeviceSharding
 
+from repro.kernels.causal_conv import ops as conv_ops
 from repro.kernels.flash_attention import ops as flash_ops
 from repro.kernels.rmsnorm import ops as rmsnorm_ops
 from repro.kernels.ssd_scan import ops as ssd_ops
@@ -53,7 +54,7 @@ def one_chip(topo):
 def compiled_kernels(monkeypatch):
     """The kernels' own compile path: the wrappers interpret on the CPU
     backend, which is what this process has."""
-    for mod in (flash_ops, rmsnorm_ops, ssd_ops):
+    for mod in (flash_ops, rmsnorm_ops, ssd_ops, conv_ops):
         monkeypatch.setattr(mod, "_should_interpret", lambda: False)
 
 
@@ -182,3 +183,53 @@ class TestSSDVJPCompilesForV5e:
         want = {("bf16", rows * s * g * n)} | (
             {("f32", heads)} if mesh_shape[0] > 1 else set())
         assert _collectives(text) == {"all-reduce": want}
+
+
+def _conv_shapes(b, s, total, width):
+    return (((b, s, total), BF16), ((4, width), BF16), ((width,), BF16),
+            ((b, s, width), F32))
+
+
+def _conv_grad(offset):
+    """Loss and gradients of xs, w and b through the conv pair, reading
+    the channels [offset, offset + C) of xs in place."""
+    def loss(xs, w, b, wy):
+        y = conv_ops.causal_conv_silu(xs, w, b, offset)
+        return jnp.sum(y.astype(F32) * wy)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+def _assert_conv_pair(text):
+    assert "tpu_custom_call" in text
+    assert "causal_conv_fwd" in text and "causal_conv_bwd" in text
+    assert "all-gather" not in _collectives(text)
+
+
+@pytest.mark.usefixtures("compiled_kernels")
+class TestCausalConvVJPCompilesForV5e:
+    """The conv pair under ``jax.grad`` at the benchmark cells' widths,
+    its channels read from ``in_proj``'s output ([B, S, 2·d_inner + 2·G·N
+    + H]) at offset d_inner."""
+
+    def test_grad_one_chip(self, one_chip):
+        # mamba2-370m.train: 16 rows of 2048, channels [2048, 4352)
+        _assert_conv_pair(_compile_for(one_chip, _conv_grad(2048),
+                                       *_conv_shapes(16, 2048, 4384, 2304)))
+
+    def test_grad_sharded_over_data(self, topo):
+        """zamba2-7b's (4, 1) mesh: each chip runs the pair on its own 2
+        rows of 4096 in ``shard_map``; the only exchanges sum the
+        replicated taps' and bias's gradients, and the loss."""
+        mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
+        specs = (PartitionSpec("data"), PartitionSpec(), PartitionSpec(),
+                 PartitionSpec("data"))
+        args = [jax.ShapeDtypeStruct(sh, d, sharding=NamedSharding(mesh, sp))
+                for (sh, d), sp in zip(_conv_shapes(8, 4096, 14704, 7424),
+                                       specs)]
+        with mesh:
+            text = jax.jit(_conv_grad(7168)).lower(*args).compile().as_text()
+        _assert_conv_pair(text)
+        reduced = _collectives(text)
+        assert set(reduced) == {"all-reduce"}
+        # the loss, the taps' [4, 7424] and the bias's [7424] gradients
+        assert {n for _, n in reduced["all-reduce"]} == {1, 4 * 7424, 7424}
